@@ -15,6 +15,22 @@ ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 BENCH_DIR = os.path.join(ROOT, "experiments", "bench")
 PROFILE_PATH = os.path.join(ROOT, "experiments", "profiles",
                             "container.json")
+#: JAX's persistent compile cache when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed path, since the path is part of every entry's key
+COMPILE_CACHE_DIR = os.path.join(ROOT, ".jax_compile_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call before the first
+    compile.  ``JAX_COMPILATION_CACHE_DIR``, when set, is honoured as is
+    (JAX reads it itself); otherwise the cache lives at
+    :data:`COMPILE_CACHE_DIR`.  Returns the directory in use."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def container_profile(refresh: bool = False):

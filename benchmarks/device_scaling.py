@@ -1,8 +1,12 @@
-"""Device-scaling measurements: sharded sweep scoring at 1 vs 4 devices.
+"""Device-scaling measurements: sharded sweep scoring at 1 vs N devices.
 
-JAX pins its device list at backend init, so one process cannot measure
-two device counts — each measurement runs in a *child* process launched
-under ``--xla_force_host_platform_device_count=N`` (see
+On an accelerator the process's own devices are the measurement: the
+1-device side is the flat ``shard=False`` path (or a one-shard service)
+and the N-device side shards across every local device, both in this
+process — it already holds the chips, so a child could not reach them.
+On the CPU, JAX pins its device list at backend init, so the two device
+counts run in *child* processes launched under
+``--xla_force_host_platform_device_count=N`` (see
 :mod:`repro.testing.devices`).  The children print one machine-readable
 JSON line; the parent computes the scaling ratios:
 
@@ -14,11 +18,11 @@ JSON line; the parent computes the scaling ratios:
   across the scoring-shard pool.
 
 The acceptance bar (sharded >= 2x the single-device path at 4 devices)
-is only physically meaningful when 4 forced host devices map onto >= 4
-physical cores — XLA's host "devices" are threads, so on a 1-core
-container they time-share the core and the ratio measures scheduler
-overhead, not scaling.  ``_apply_bar`` therefore asserts the bar when
-``os.cpu_count() >= BAR_MIN_CORES`` and otherwise records an explicit
+needs 4 devices, and forced host devices are only physically meaningful
+when they map onto >= 4 physical cores — XLA's host "devices" are
+threads, so on a 1-core container they time-share the core and the ratio
+measures scheduler overhead, not scaling.  ``_apply_bar`` therefore
+asserts the bar only where both hold and otherwise records an explicit
 waiver string in the emitted row, so the measured numbers still land in
 the BENCH trajectory without pretending the bar was met or moving it.
 
@@ -32,7 +36,7 @@ import dataclasses
 import json
 import os
 import time
-from typing import Callable, Dict, List, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from benchmarks.common import _print_table
 
@@ -101,13 +105,16 @@ def _child_sweep(quick: bool) -> Dict:
     sharded_s = _steady_state(lambda: sweep.score(hw, shard=True))
     return {
         "devices": jax.device_count(),
+        "platform": jax.default_backend(),
         "cells": cells,
         "flat_cells_per_s": cells / max(flat_s, 1e-12),
         "sharded_cells_per_s": cells / max(sharded_s, 1e-12),
     }
 
 
-def _child_serving(quick: bool) -> Dict:
+def _child_serving(quick: bool, shards: Optional[int] = None) -> Dict:
+    """Questions/sec through a service routing over ``shards`` scoring
+    shards (default: every local device)."""
     import jax
     from repro.core.hardware import hw1
     from repro.serving import DesignCalculatorService
@@ -121,7 +128,7 @@ def _child_serving(quick: bool) -> Dict:
     variants = [[dataclasses.replace(w, n_queries=100 + q)
                  for w in workloads] for q in range(n_questions)]
     service = DesignCalculatorService(
-        [hw], scoring_shards=jax.device_count(),
+        [hw], scoring_shards=shards or jax.device_count(),
         shard_min_cells=max((n_designs * n_points) // 8, 1),
         window_s=0.005)
     try:
@@ -137,7 +144,8 @@ def _child_serving(quick: bool) -> Dict:
     finally:
         service.stop()
     return {
-        "devices": jax.device_count(),
+        "devices": shards or jax.device_count(),
+        "platform": jax.default_backend(),
         "questions": n_questions,
         "questions_per_s": n_questions / max(wall, 1e-12),
         "shard_dispatches": stats["shard_dispatches"],
@@ -164,11 +172,32 @@ def _run_child(mode: str, n_devices: int, quick: bool) -> Dict:
                        f"measurement line:\n{proc.stdout[-2000:]}")
 
 
-def _apply_bar(row: Dict, speedup_key: str) -> Dict:
-    """Assert the >= 2x bar, or record a waiver on hardware where 4
-    forced host devices cannot occupy 4 physical cores."""
+def _measure(mode: str, quick: bool) -> Tuple[Dict, Dict]:
+    """The (1-device, N-device) measurements of one child mode: in this
+    process on an accelerator, in forced-host-device children on the
+    CPU."""
+    import jax
+    if jax.default_backend() == "cpu":
+        return (_run_child(mode, 1, quick),
+                _run_child(mode, BAR_DEVICES, quick))
+    if mode == "sweep":
+        both = _child_sweep(quick)    # flat vs sharded, one process
+        return both, both
+    return _child_serving(quick, shards=1), _child_serving(quick)
+
+
+def _apply_bar(row: Dict, speedup_key: str, multi: Dict) -> Dict:
+    """Assert the >= 2x bar, or record a waiver where the N-device side
+    has fewer than 4 devices or its 4 forced host devices cannot occupy
+    4 physical cores."""
     cores = os.cpu_count() or 1
-    if cores >= BAR_MIN_CORES:
+    row["devices"] = multi["devices"]
+    row["platform"] = multi["platform"]
+    if multi["devices"] < BAR_DEVICES:
+        row["scaling_bar"] = (
+            f"waived: {multi['devices']} {multi['platform']} device(s) < "
+            f"{BAR_DEVICES} (measured ratio recorded unchanged)")
+    elif multi["platform"] != "cpu" or cores >= BAR_MIN_CORES:
         row["scaling_bar"] = f"asserted >= {SCALING_TARGET:.0f}x"
         assert row[speedup_key] >= SCALING_TARGET, \
             (f"{speedup_key} = {row[speedup_key]:.2f}x is below the "
@@ -187,10 +216,9 @@ def _apply_bar(row: Dict, speedup_key: str) -> Dict:
 # parent rows, consumed by search_bench / load_bench trajectories
 # ---------------------------------------------------------------------------
 def sweep_scaling_row(quick: bool = False) -> Dict:
-    """Sweep-grid cells/sec at 1 vs BAR_DEVICES forced devices — the
-    BENCH_search device-scaling row."""
-    base = _run_child("sweep", 1, quick)
-    multi = _run_child("sweep", BAR_DEVICES, quick)
+    """Sweep-grid cells/sec at 1 vs N devices — the BENCH_search
+    device-scaling row."""
+    base, multi = _measure("sweep", quick)
     speedup = multi["sharded_cells_per_s"] / max(
         base["flat_cells_per_s"], 1e-12)
     return _apply_bar({
@@ -201,14 +229,13 @@ def sweep_scaling_row(quick: bool = False) -> Dict:
         "sweep_cells_per_s": base["flat_cells_per_s"],
         "sharded_cells_per_s_4dev": multi["sharded_cells_per_s"],
         "speedup_sharded_4dev_vs_1dev": speedup,
-    }, "speedup_sharded_4dev_vs_1dev")
+    }, "speedup_sharded_4dev_vs_1dev", multi)
 
 
 def serving_scaling_row(quick: bool = False) -> Dict:
-    """Service questions/sec at 1 vs BAR_DEVICES scoring shards — the
-    BENCH_load device-scaling fields."""
-    base = _run_child("serving", 1, quick)
-    multi = _run_child("serving", BAR_DEVICES, quick)
+    """Service questions/sec at 1 vs N scoring shards — the BENCH_load
+    device-scaling fields."""
+    base, multi = _measure("serving", quick)
     speedup = multi["questions_per_s"] / max(base["questions_per_s"],
                                              1e-12)
     return _apply_bar({
@@ -216,7 +243,7 @@ def serving_scaling_row(quick: bool = False) -> Dict:
         "questions_per_s_4dev": multi["questions_per_s"],
         "shard_dispatches_4dev": multi["shard_dispatches"],
         "speedup_serving_4dev_vs_1dev": speedup,
-    }, "speedup_serving_4dev_vs_1dev")
+    }, "speedup_serving_4dev_vs_1dev", multi)
 
 
 def _smoke() -> None:

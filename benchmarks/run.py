@@ -27,6 +27,7 @@ from benchmarks import (chaos_bench, design_space, device_scaling,
                         fig8_cache_skew, fig9_design_search, hillclimb,
                         kernels_bench, load_bench, popsearch_bench,
                         roofline, search_bench, serving_bench)
+from benchmarks.common import enable_compile_cache
 
 BENCHES = [
     ("design_space", design_space.run),
@@ -67,6 +68,7 @@ def main() -> None:
                          " no trajectory append")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         t0 = time.perf_counter()
         print("### repro-lint (smoke)", flush=True)

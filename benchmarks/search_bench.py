@@ -514,11 +514,11 @@ def run(quick: bool = False, smoke: bool = False) -> None:
     assert sweep["speedup_sweep_vs_per_workload"] >= \
         SWEEP_TARGET_SPEEDUP, \
         "the workload-sweep engine regressed below the PR-5 bar"
-    # device scaling: sweep cells/sec at 1 vs 4 forced host devices,
-    # measured in subprocesses (the device count is fixed at backend
-    # init).  The >= 2x bar is asserted inside sweep_scaling_row when
-    # this host has >= 4 physical cores, and recorded as an explicit
-    # waiver otherwise — either way the measured row joins the
+    # device scaling: sweep cells/sec at 1 vs N devices (forced host
+    # devices in subprocesses on the CPU, this process's own devices on
+    # an accelerator).  The >= 2x bar is asserted inside
+    # sweep_scaling_row where 4 devices can scale, and recorded as an
+    # explicit waiver otherwise — either way the measured row joins the
     # trajectory.
     from benchmarks import device_scaling
     scaling = device_scaling.sweep_scaling_row(quick)

@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import operator
 import os
 import time
 from typing import Dict, List, Optional, Tuple
@@ -50,6 +51,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import memo
 from repro.core.hardware import HardwareProfile
@@ -287,15 +289,29 @@ def invalidate_table(hw: HardwareProfile) -> None:
 _BANK_REPLICAS = memo.DictCache(maxsize=32, name="device_banks")
 
 
+def _stack_on(devices, blocks: np.ndarray) -> jax.Array:
+    """``blocks`` ``[len(devices), ...]`` committed one leading-axis block
+    per device — the pmap input layout — via ``jax.device_put`` onto a
+    1-D mesh sharding."""
+    mesh = Mesh(np.asarray(devices), ("shard",))
+    return jax.device_put(blocks, NamedSharding(mesh, P("shard")))
+
+
+def _replicate_on(devices, x) -> jax.Array:
+    """One copy of ``x`` per device, stacked along a new leading axis."""
+    x = np.asarray(x)
+    return _stack_on(devices, np.broadcast_to(x, (len(devices),) + x.shape))
+
+
 def replicated_banks(table: DeviceTable, n_dev: int) -> Dict[str, jax.Array]:
-    """``table.banks`` stacked across the first ``n_dev`` local devices
-    (``jax.device_put_replicated``), ready as a leading-axis pmap input."""
+    """``table.banks`` stacked across the first ``n_dev`` local devices,
+    ready as a leading-axis pmap input."""
     key = (id(table), n_dev)
     hit = _BANK_REPLICAS.get(key)
     if hit is not None and hit[0] is table:
         return hit[1]
-    stacked = jax.device_put_replicated(table.banks,
-                                        jax.local_devices()[:n_dev])
+    devices = jax.local_devices()[:n_dev]
+    stacked = {k: _replicate_on(devices, v) for k, v in table.banks.items()}
     _BANK_REPLICAS.put(key, (table, stacked))
     return stacked
 
@@ -341,16 +357,30 @@ def bank_predict(banks: Dict[str, jax.Array], ids: jax.Array,
     bank rows the fused engine scores with.  knn rows join through a
     ``top_k`` gather whose value-gradients flow through the inverse
     log-distance weights.
+
+    The short per-record sums (basis features, sigmoid slots, knn
+    neighbours) are written as explicit left-to-right adds over bank
+    columns, never as a ``sum`` over a trailing axis: a TPU orders such
+    a reduction by the array's layout, which follows the batch shape, so
+    a ``[2, R]`` pmap shard and a ``[8, R]`` flat chunk would round the
+    same record differently.  Written out, every record's value is the
+    same whatever the shape, which is what keeps sharded, chunked and
+    flat scores equal bit for bit.
     """
+    def col(name: str, j: int) -> jax.Array:
+        return banks[name][:, j][ids]
+
     x = jnp.clip(x, banks["xlo"][ids], banks["xhi"][ids])
     lx = jnp.log(x + 1.0)
 
-    feats = jnp.stack([x, lx, jnp.log(lx + 1.0), x * lx], axis=-1)
-    lin = (feats * banks["lin_w"][ids]).sum(-1) + banks["lin_y0"][ids]
+    feats = (x, lx, jnp.log(lx + 1.0), x * lx)
+    lin = _ordered_sum([f * col("lin_w", j) for j, f in enumerate(feats)]
+                       ) + banks["lin_y0"][ids]
 
-    sig = (jax.nn.sigmoid(banks["sig_k"][ids] *
-                          (lx[..., None] - banks["sig_x0"][ids])) *
-           banks["sig_c"][ids]).sum(-1) + banks["sig_y0"][ids]
+    sig = _ordered_sum([
+        jax.nn.sigmoid(col("sig_k", j) * (lx - col("sig_x0", j)))
+        * col("sig_c", j) for j in range(banks["sig_k"].shape[1])]
+    ) + banks["sig_y0"][ids]
 
     kind = banks["kinds"][ids]
     y = jnp.where(kind == KIND_SIGMOID, sig, lin)
@@ -361,9 +391,17 @@ def bank_predict(banks: Dict[str, jax.Array], ids: jax.Array,
         wk, idx = jax.lax.top_k(w, 4)
         yk = jnp.take_along_axis(
             jnp.broadcast_to(banks["knn_y"][ids], w.shape), idx, axis=-1)
-        knn = (wk * yk).sum(-1) / jnp.maximum(wk.sum(-1), 1e-30)
+        knn = _ordered_sum([wk[..., j] * yk[..., j] for j in range(4)]) / \
+            jnp.maximum(_ordered_sum([wk[..., j] for j in range(4)]), 1e-30)
         y = jnp.where(kind == KIND_KNN, knn, y)
     return jnp.maximum(y, 0.0)
+
+
+def _ordered_sum(terms: List[jax.Array]) -> jax.Array:
+    """``terms[0] + terms[1] + ...`` in that order (see
+    :func:`bank_predict`: an explicit chain of adds rounds the same on
+    every array shape)."""
+    return functools.reduce(operator.add, terms)
 
 
 def _score_kernel(banks: Dict[str, jax.Array], ids: jax.Array,
@@ -577,6 +615,21 @@ def _check_frontier(table: DeviceTable, ids: np.ndarray) -> None:
                        f"model for: {missing}")
 
 
+def _chunk_cuts(tile_segments: np.ndarray, chunk_tiles: int) -> List[int]:
+    """Tile offsets that cut a record layout into fused chunks of at most
+    ``chunk_tiles`` tiles, each cut on a design boundary: every design's
+    records then reduce in one ``segment_sum``, so a chunked score equals
+    a one-call (or sharded) score bit for bit.  Only a design longer than
+    a whole chunk is cut inside."""
+    n = len(tile_segments)
+    cuts = [0]
+    while cuts[-1] + chunk_tiles < n:
+        end = cuts[-1] + chunk_tiles
+        start = int(np.searchsorted(tile_segments, tile_segments[end]))
+        cuts.append(start if start > cuts[-1] else end)
+    return cuts + [n]
+
+
 def score_frontier(ids: np.ndarray, sizes: np.ndarray, weights: np.ndarray,
                    tile_segments: np.ndarray, n_segments: int,
                    hw: HardwareProfile,
@@ -610,9 +663,10 @@ def score_frontier(ids: np.ndarray, sizes: np.ndarray, weights: np.ndarray,
                            n_segments))
     banks = table.banks if device is None else _banks_on(table, device)
     totals = np.zeros(n_pad, np.float64)
-    for lo in range(0, max(len(ids), 1), _MAX_FUSED_RECORDS):
-        chunk = slice(lo, lo + _MAX_FUSED_RECORDS)
-        tile_chunk = slice(lo // TILE, (lo + _MAX_FUSED_RECORDS) // TILE)
+    cuts = _chunk_cuts(tile_segments, _MAX_FUSED_RECORDS // TILE)
+    for t0, t1 in zip(cuts[:-1], cuts[1:]):
+        chunk = slice(t0 * TILE, t1 * TILE)
+        tile_chunk = slice(t0, t1)
         bucket = _pow2(len(ids[chunk]), 16)
         padded = _pad_records(ids[chunk], sizes[chunk], weights[chunk],
                               tile_segments[tile_chunk], bucket)
@@ -707,7 +761,7 @@ def shard_sweep(ids: np.ndarray, sizes: np.ndarray, weights: np.ndarray,
     output back to ``[:W]``, so pad rows are computed-and-dropped, never
     observable — the sharded grid stays bit-identical to the flat call.
     Returns ``(w_axis, (ids, sizes, weights, tile_segments))`` where
-    ``sizes``/``weights`` are pmap-sharded (``jax.device_put_sharded``)
+    ``sizes``/``weights`` are pmap-sharded (one row block per device)
     and ``ids``/``tile_segments`` replicated: a retained sweep keeps the
     tuple and every repeat score is a pure pmap dispatch with zero
     host->device copies."""
@@ -722,13 +776,10 @@ def shard_sweep(ids: np.ndarray, sizes: np.ndarray, weights: np.ndarray,
         weights = np.concatenate(
             [weights, np.zeros((pad, weights.shape[1]), np.float32)])
     return w_axis, (
-        jax.device_put_replicated(np.asarray(ids, np.int32), devices),
-        jax.device_put_sharded(list(sizes.reshape(n_dev, w_shard, -1)),
-                               devices),
-        jax.device_put_sharded(list(weights.reshape(n_dev, w_shard, -1)),
-                               devices),
-        jax.device_put_replicated(np.asarray(tile_segments, np.int32),
-                                  devices))
+        _replicate_on(devices, np.asarray(ids, np.int32)),
+        _stack_on(devices, sizes.reshape(n_dev, w_shard, -1)),
+        _stack_on(devices, weights.reshape(n_dev, w_shard, -1)),
+        _replicate_on(devices, np.asarray(tile_segments, np.int32)))
 
 
 def _sweep_sharded(table: DeviceTable, state: Tuple,
@@ -834,9 +885,10 @@ def score_sweep(ids, sizes, weights, tile_segments, n_segments: int,
     sizes, weights = np.asarray(sizes), np.asarray(weights)
     tile_segments = np.asarray(tile_segments)
     totals = np.zeros((w_axis, n_pad), np.float64)
-    for lo in range(0, max(n, 1), chunk_r):
-        chunk = slice(lo, lo + chunk_r)
-        tile_chunk = slice(lo // TILE, (lo + chunk_r) // TILE)
+    cuts = _chunk_cuts(tile_segments, chunk_r // TILE)
+    for t0, t1 in zip(cuts[:-1], cuts[1:]):
+        chunk = slice(t0 * TILE, t1 * TILE)
+        tile_chunk = slice(t0, t1)
         bucket = _pow2(len(ids[chunk]), 16)
         padded = pad_sweep(ids[chunk], sizes[:, chunk], weights[:, chunk],
                            tile_segments[tile_chunk], bucket)

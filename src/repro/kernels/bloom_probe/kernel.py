@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.runtime import BLOCK_1D, resolve_interpret
 
 
 def _bloom_kernel(words_ref, queries_ref, coeffs_ref, hits_ref, *,
@@ -51,7 +51,7 @@ def _bloom_kernel(words_ref, queries_ref, coeffs_ref, hits_ref, *,
 
 def bloom_probe_kernel(words: jax.Array, queries: jax.Array,
                        coeffs: jax.Array, *, s: int,
-                       block_q: int = 256, block_w: int = 256,
+                       block_q: int = BLOCK_1D, block_w: int = BLOCK_1D,
                        interpret: Optional[bool] = None) -> jax.Array:
     """words: [W] uint32 filter (W = 2^s / 32); queries: [Q];
     coeffs: [k] uint32 odd hash multipliers.

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.runtime import BLOCK_1D, resolve_interpret
 
 #: deterministic odd multipliers (the paper draws them randomly per run)
 DEFAULT_COEFFS = np.array([0x9E3779B1, 0x85EBCA77, 0xC2B2AE3D, 0x27D4EB2F,
@@ -25,7 +25,8 @@ def _pad1(x: jax.Array, mult: int, value) -> jax.Array:
 @functools.partial(jax.jit, static_argnames=("s", "num_hashes", "block_q",
                                              "block_w", "interpret"))
 def bloom_probe(words: jax.Array, queries: jax.Array, s: int,
-                num_hashes: int = 2, block_q: int = 256, block_w: int = 256,
+                num_hashes: int = 2, block_q: int = BLOCK_1D,
+                block_w: int = BLOCK_1D,
                 interpret: Optional[bool] = None) -> jax.Array:
     """Membership mask for ``queries`` against a 2^s-bit bloom filter."""
     from repro.kernels.bloom_probe.kernel import bloom_probe_kernel

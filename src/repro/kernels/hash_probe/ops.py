@@ -8,7 +8,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.hash_probe.kernel import NOT_FOUND, hash_probe_kernel
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.hash_probe.ref import EMPTY_KEY
+from repro.kernels.runtime import BLOCK_1D, resolve_interpret
 
 #: default multiply-shift coefficient (odd, from a fixed PRNG draw — the
 #: paper draws a randomly per run; determinism helps tests)
@@ -22,21 +23,23 @@ def _pad1(x: jax.Array, mult: int, value) -> jax.Array:
     return jnp.concatenate([x, jnp.full((pad,), value, x.dtype)])
 
 
-@functools.partial(jax.jit, static_argnames=("a", "s", "block_q", "block_nb",
+@functools.partial(jax.jit, static_argnames=("a", "s", "block_q", "block_k",
                                              "interpret"))
 def hash_probe(table_keys: jax.Array, table_values: jax.Array,
                queries: jax.Array, s: int, a: int = DEFAULT_A,
-               block_q: int = 256, block_nb: int = 64,
+               block_q: int = BLOCK_1D, block_k: int = BLOCK_1D,
                interpret: Optional[bool] = None):
     """(found mask, values) for point probes against a bucketized table."""
     interpret = resolve_interpret(interpret)
     q = queries.shape[0]
-    nb = table_keys.shape[0]
-    block_nb = min(block_nb, nb)
+    cap = table_keys.shape[1]
+    # flat bucket-major slots; padding slots lie past every bucket's range
+    keys_p = _pad1(table_keys.reshape(-1), block_k, EMPTY_KEY)
+    vals_p = _pad1(table_values.reshape(-1), block_k, 0)
     queries_p = _pad1(queries, block_q, jnp.asarray(NOT_FOUND - 1,
                                                     queries.dtype))
-    pos, val = hash_probe_kernel(table_keys, table_values, queries_p,
+    pos, val = hash_probe_kernel(keys_p, vals_p, queries_p, cap=cap,
                                  a=a, s=s, block_q=block_q,
-                                 block_nb=block_nb, interpret=interpret)
+                                 block_k=block_k, interpret=interpret)
     found = pos[:q] != NOT_FOUND
     return found, jnp.where(found, val[:q], 0)

@@ -16,14 +16,18 @@ from typing import Optional
 
 import jax
 
+#: default width of the kernels' 1-D blocks: XLA tiles a 1-D 32-bit array
+#: on a TPU as T(1024), and Mosaic refuses a block that does not match
+#: ("XLA layout ({0:T(1024)}) does not match Mosaic layout")
+BLOCK_1D = 1024
+
 
 @functools.lru_cache(maxsize=None)
 def default_interpret() -> bool:
-    """True (interpret) unless a real TPU backend is attached."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # no backend at all -> interpreter is the only option
-        return True
+    """True (interpret) unless a real TPU backend is attached.  A backend
+    that fails to initialize raises: it must never quietly fall back to
+    the interpreter on the machine that was meant to run the kernels."""
+    return jax.default_backend() != "tpu"
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.runtime import BLOCK_1D, resolve_interpret
 
 NOT_FOUND = 2147483647  # int32 max; plain int so kernels don't capture it
 
@@ -54,7 +54,7 @@ def _scan_kernel(keys_ref, queries_ref, lo_ref, hi_ref, pos_ref, cnt_ref, *,
 
 def scan_filter_kernel(keys: jax.Array, queries: jax.Array,
                        lo: jax.Array, hi: jax.Array, *,
-                       block_q: int = 256, block_k: int = 512,
+                       block_q: int = BLOCK_1D, block_k: int = BLOCK_1D,
                        interpret: Optional[bool] = None):
     """keys: [N] unsorted; queries/lo/hi: [Q].
 

@@ -7,7 +7,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.runtime import BLOCK_1D, resolve_interpret
 from repro.kernels.scan_filter.kernel import NOT_FOUND, scan_filter_kernel
 
 
@@ -22,7 +22,7 @@ def _pad1(x: jax.Array, mult: int, value) -> jax.Array:
                                              "interpret"))
 def scan_filter(keys: jax.Array, queries: jax.Array,
                 lo: jax.Array, hi: jax.Array,
-                block_q: int = 256, block_k: int = 512,
+                block_q: int = BLOCK_1D, block_k: int = BLOCK_1D,
                 interpret: Optional[bool] = None):
     """(first-match pos | NOT_FOUND, range count) over an unsorted node."""
     interpret = resolve_interpret(interpret)

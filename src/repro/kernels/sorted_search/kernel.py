@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.runtime import BLOCK_1D, resolve_interpret
 
 
 def _search_kernel(keys_ref, queries_ref, o_ref, *, block_k: int):
@@ -43,7 +43,7 @@ def _search_kernel(keys_ref, queries_ref, o_ref, *, block_k: int):
 
 
 def sorted_search_kernel(keys: jax.Array, queries: jax.Array, *,
-                         block_q: int = 256, block_k: int = 512,
+                         block_q: int = BLOCK_1D, block_k: int = BLOCK_1D,
                          interpret: Optional[bool] = None) -> jax.Array:
     """keys: [N] sorted ascending; queries: [Q].
 

@@ -11,7 +11,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.runtime import BLOCK_1D, resolve_interpret
 from repro.kernels.sorted_search.kernel import sorted_search_kernel
 
 
@@ -26,7 +26,7 @@ def _pad1(x: jax.Array, mult: int, value) -> jax.Array:
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
                                              "interpret"))
 def sorted_search(keys: jax.Array, queries: jax.Array,
-                  block_q: int = 256, block_k: int = 512,
+                  block_q: int = BLOCK_1D, block_k: int = BLOCK_1D,
                   interpret: Optional[bool] = None) -> jax.Array:
     """searchsorted(keys, queries, side='right') via the Pallas kernel.
 

@@ -1,8 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"   # a CPU dry run: never claim a chip
 
-# NOTE: no `from __future__ import annotations` here — the XLA_FLAGS export
-# above must stay the first executable statement, before any jax import.
+# NOTE: no `from __future__ import annotations` here — the XLA_FLAGS and
+# JAX_PLATFORMS exports above must stay the first executable statements,
+# before any jax import.
 
 """Multi-pod dry-run: .lower().compile() every (arch x shape x mesh) cell.
 
@@ -43,7 +45,7 @@ from repro.configs.base import (ArchConfig, RunConfig, SHAPES, ShapeConfig,
                                 shape_applies)
 from repro.core import distcalc
 from repro.core.hardware import TPU_V5E
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models import build
 from repro.models.registry import Model
 from repro.parallel import (batch_sharding, cache_shardings, data_axes,
@@ -308,7 +310,7 @@ def measure_cell(arch: str, shape_name: str, mesh_kind: str,
 
     if "mesh_shape" in variant:  # e.g. (32, 8): same 256 chips, TP=8
         d, m = variant["mesh_shape"]
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     sp = variant.get("seq_parallel", shape.kind == "train")

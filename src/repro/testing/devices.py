@@ -13,7 +13,8 @@ build on the primitives here:
   test in a subprocess under exactly ``n`` forced devices, so one CI
   invocation covers 2/8/48-way sharding;
 * ``benchmarks/device_scaling.py`` runs measurement children at 1 and 4
-  devices and compares cells/sec.
+  devices and compares cells/sec (on the CPU only: on an accelerator the
+  parent holds the chips, so it measures in-process).
 """
 from __future__ import annotations
 

@@ -72,3 +72,28 @@ def test_emit_trajectory_write_is_atomic(bench_dir, monkeypatch):
         common.emit_trajectory("BENCH_x", "doomed", [{"a": 2}])
     assert path.read_text() == before
     assert [f for f in os.listdir(bench_dir) if f != "BENCH_x.json"] == []
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_enable_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """``JAX_COMPILATION_CACHE_DIR`` wins untouched; otherwise the cache
+    lives at one fixed, gitignored directory of the checkout."""
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        path = common.enable_compile_cache()
+        if from_env:
+            assert path == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert path == common.COMPILE_CACHE_DIR
+            assert jax.config.jax_compilation_cache_dir == path
+            assert os.path.dirname(path) == common.ROOT
+            with open(os.path.join(common.ROOT, ".gitignore")) as fh:
+                assert os.path.basename(path) + "/" in fh.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

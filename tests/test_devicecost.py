@@ -64,7 +64,23 @@ def test_chunked_scoring_matches_unchunked(hw_analytical, monkeypatch):
     full = packed.score(hw_analytical)
     monkeypatch.setattr(devicecost, "_MAX_FUSED_RECORDS", 256)
     chunked = packed.score(hw_analytical)
-    np.testing.assert_allclose(chunked, full, rtol=1e-6)
+    # chunks cut on design boundaries: every design reduces in one call
+    np.testing.assert_array_equal(chunked, full)
+
+
+def test_chunked_sweep_matches_one_call_bitwise(hw_analytical, monkeypatch):
+    """A sweep too large for one fused chunk scores chunk by chunk; cut on
+    design boundaries, the grid equals the one-call grid bit for bit (the
+    equality the sharded path's parity with the flat path rests on)."""
+    specs, w, mix = _frontier()
+    workloads = [w, Workload(n_entries=500_000, zipf_alpha=1.0)]
+    full = batchcost.pack_sweep(specs * 40, workloads, mix).score(
+        hw_analytical, shard=False)
+    monkeypatch.setattr(devicecost, "_MAX_FUSED_RECORDS", 512)
+    batchcost.clear_caches()
+    chunked = batchcost.pack_sweep(specs * 40, workloads, mix).score(
+        hw_analytical, shard=False)
+    np.testing.assert_array_equal(chunked, full)
 
 
 def _knn_profile(base: HardwareProfile, n_points: int) -> HardwareProfile:
@@ -88,6 +104,24 @@ def test_knn_models_join_the_device_table(hw_analytical, n_points):
     np.testing.assert_allclose(fused, grouped, rtol=1e-6)
     table = devicecost.device_table(hw)
     assert table.has_knn
+
+
+@pytest.mark.parametrize("with_knn", [False, True], ids=["plain", "knn"])
+def test_bank_predict_sums_in_a_fixed_order(hw_analytical, with_knn):
+    """Per-record values must not depend on the batch shape: a TPU orders
+    a ``reduce_sum`` by layout, so a [2, R] pmap shard and an [8, R]
+    flat chunk rounded the same record differently.  bank_predict spells
+    its short sums out as ordered adds — no reduction primitive at all."""
+    import jax
+    import jax.numpy as jnp
+    table = devicecost.device_table(_knn_profile(hw1(), 12) if with_knn
+                                    else hw1())
+    ids = jnp.zeros(64, jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda x: devicecost.bank_predict(
+        table.banks, ids, x, with_knn))(jnp.ones((2, 64), jnp.float32))
+    prims = {eqn.primitive.name for eqn in jaxpr.jaxpr.eqns}
+    assert not {p for p in prims if p.startswith("reduce_")}, prims
+    assert ("top_k" in prims) == with_knn
 
 
 def test_sigmoids2d_banks_as_its_m1_slice(hw_analytical):
