@@ -53,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import memo
+from repro.core import memo, spans
 from repro.core.hardware import HardwareProfile
 from repro.core.memo import MEMO_LOCK
 from repro.core.models import _BASES, KNN_SENTINEL
@@ -607,6 +607,19 @@ def _calibrate_shard_threshold() -> int:
     return 4 * _CALIBRATION_BUCKETS[-1]
 
 
+def _dispatch(records: int, points: int, device=None) -> spans.span:
+    """The span around padding, placing and issuing one fused call
+    (``device`` -1: the default device)."""
+    return spans.span(spans.DISPATCH, records=records, points=points,
+                      device=-1 if device is None else device.id)
+
+
+def _fetch(out, records: int) -> np.ndarray:
+    """One fused call's output on the host, as float64, under its span."""
+    with spans.span(spans.FETCH, records=records):
+        return np.asarray(out, np.float64)
+
+
 def _check_frontier(table: DeviceTable, ids: np.ndarray) -> None:
     if len(ids) and not table.avail[ids].all():
         missing = sorted({_MODEL_NAMES[m] for m in np.unique(ids)
@@ -668,12 +681,13 @@ def score_frontier(ids: np.ndarray, sizes: np.ndarray, weights: np.ndarray,
         chunk = slice(t0 * TILE, t1 * TILE)
         tile_chunk = slice(t0, t1)
         bucket = _pow2(len(ids[chunk]), 16)
-        padded = _pad_records(ids[chunk], sizes[chunk], weights[chunk],
-                              tile_segments[tile_chunk], bucket)
-        if device is not None:
-            padded = tuple(jax.device_put(a, device) for a in padded)
-        out = _score_jit(banks, *padded, n_pad, table.has_knn)
-        totals += np.asarray(out, np.float64)
+        with _dispatch(bucket, 1, device):
+            padded = _pad_records(ids[chunk], sizes[chunk], weights[chunk],
+                                  tile_segments[tile_chunk], bucket)
+            if device is not None:
+                padded = tuple(jax.device_put(a, device) for a in padded)
+            out = _score_jit(banks, *padded, n_pad, table.has_knn)
+        totals += _fetch(out, bucket)
     return faults.corrupt("devicecost.fused", totals[:n_segments])
 
 
@@ -726,8 +740,9 @@ def to_device_sweep(ids, sizes, weights, tile_segments) -> Tuple:
     chunk loop slices them."""
     if len(ids) > sweep_chunk(sizes.shape[0]):
         return ids, sizes, weights, tile_segments
-    return tuple(jnp.asarray(a)
-                 for a in (ids, sizes, weights, tile_segments))
+    with _dispatch(len(ids), int(sizes.shape[0])):
+        return tuple(jnp.asarray(a)
+                     for a in (ids, sizes, weights, tile_segments))
 
 
 def sweep_shard_count(w_axis: int, n_records: int,
@@ -788,11 +803,13 @@ def _sweep_sharded(table: DeviceTable, state: Tuple,
     bank replicas, output rows re-flattened and pad rows sliced off."""
     w_axis, (ids_sh, sizes_sh, weights_sh, tiles_sh) = state
     n_dev = int(sizes_sh.shape[0])
-    out = np.asarray(
-        _sweep_pmap(_pow2(n_segments, 16), table.has_knn)(
+    records = int(ids_sh.shape[-1])
+    with spans.span(spans.DISPATCH, records=records, points=w_axis,
+                    devices=n_dev):
+        out = _sweep_pmap(_pow2(n_segments, 16), table.has_knn)(
             replicated_banks(table, n_dev), ids_sh, sizes_sh, weights_sh,
-            tiles_sh),
-        np.float64)
+            tiles_sh)
+    out = _fetch(out, records)
     return out.reshape(-1, out.shape[-1])[:w_axis, :n_segments]
 
 
@@ -853,13 +870,14 @@ def score_sweep(ids, sizes, weights, tile_segments, n_segments: int,
         n_dev = sweep_shard_count(w_axis, n, shard)
         if (n_dev > 1 or (shard is True and w_axis > 1)) and \
                 _pow2(n, 16) <= sweep_chunk(-(-w_axis // n_dev)):
-            padded = pad_sweep(host_ids, np.asarray(sizes),
-                               np.asarray(weights),
-                               np.asarray(tile_segments), _pow2(n, 16))
-            return faults.corrupt(
-                "devicecost.fused",
-                _sweep_sharded(table, shard_sweep(*padded, n_dev),
-                               n_segments))
+            with spans.span(spans.DISPATCH, records=_pow2(n, 16),
+                            points=w_axis, devices=n_dev):
+                padded = pad_sweep(host_ids, np.asarray(sizes),
+                                   np.asarray(weights),
+                                   np.asarray(tile_segments), _pow2(n, 16))
+                state = shard_sweep(*padded, n_dev)
+            return faults.corrupt("devicecost.fused",
+                                  _sweep_sharded(table, state, n_segments))
         if w_axis == 1 and (shard is True or (
                 shard is None and len(jax.local_devices()) > 1
                 and n >= shard_threshold())):
@@ -874,13 +892,14 @@ def score_sweep(ids, sizes, weights, tile_segments, n_segments: int,
         # hands over cached padded device-resident arrays plus host ids,
         # so beyond the O(R) availability check above this is a pure
         # fused dispatch with zero copies
-        args = (ids, sizes, weights, tile_segments)
-        if device is not None:
-            args = tuple(jax.device_put(np.asarray(a), device)
-                         for a in args)
-        out = _sweep_jit(banks, *args, n_pad, table.has_knn)
+        with _dispatch(n, w_axis, device):
+            args = (ids, sizes, weights, tile_segments)
+            if device is not None:
+                args = tuple(jax.device_put(np.asarray(a), device)
+                             for a in args)
+            out = _sweep_jit(banks, *args, n_pad, table.has_knn)
         return faults.corrupt("devicecost.fused",
-                              np.asarray(out, np.float64)[:, :n_segments])
+                              _fetch(out, n)[:, :n_segments])
     ids = host_ids
     sizes, weights = np.asarray(sizes), np.asarray(weights)
     tile_segments = np.asarray(tile_segments)
@@ -890,12 +909,14 @@ def score_sweep(ids, sizes, weights, tile_segments, n_segments: int,
         chunk = slice(t0 * TILE, t1 * TILE)
         tile_chunk = slice(t0, t1)
         bucket = _pow2(len(ids[chunk]), 16)
-        padded = pad_sweep(ids[chunk], sizes[:, chunk], weights[:, chunk],
-                           tile_segments[tile_chunk], bucket)
-        if device is not None:
-            padded = tuple(jax.device_put(a, device) for a in padded)
-        out = _sweep_jit(banks, *padded, n_pad, table.has_knn)
-        totals += np.asarray(out, np.float64)
+        with _dispatch(bucket, w_axis, device):
+            padded = pad_sweep(ids[chunk], sizes[:, chunk],
+                               weights[:, chunk], tile_segments[tile_chunk],
+                               bucket)
+            if device is not None:
+                padded = tuple(jax.device_put(a, device) for a in padded)
+            out = _sweep_jit(banks, *padded, n_pad, table.has_knn)
+        totals += _fetch(out, bucket)
     return faults.corrupt("devicecost.fused", totals[:, :n_segments])
 
 
@@ -909,18 +930,19 @@ def _score_sharded(table: DeviceTable, ids: np.ndarray, sizes: np.ndarray,
     seg_cuts, tile_cuts = segment_ranges(tile_segments, n_segments, n_dev)
     rec_bucket = _pow2(int(max(np.diff(tile_cuts), default=1)) * TILE, 16)
     seg_pad = _pow2(int(max(np.diff(seg_cuts), default=1)), 16)
-    shards = []
-    for d in range(n_dev):
-        t0, t1 = tile_cuts[d], tile_cuts[d + 1]
-        r0, r1 = t0 * TILE, t1 * TILE
-        shards.append(_pad_records(ids[r0:r1], sizes[r0:r1],
-                                   weights[r0:r1],
-                                   tile_segments[t0:t1] - seg_cuts[d],
-                                   rec_bucket))
-    stacked = [np.stack([s[i] for s in shards]) for i in range(4)]
-    out = np.asarray(
-        _score_pmap(seg_pad, table.has_knn)(
-            replicated_banks(table, n_dev), *stacked),
-        np.float64)
+    with spans.span(spans.DISPATCH, records=rec_bucket, points=1,
+                    devices=n_dev):
+        shards = []
+        for d in range(n_dev):
+            t0, t1 = tile_cuts[d], tile_cuts[d + 1]
+            r0, r1 = t0 * TILE, t1 * TILE
+            shards.append(_pad_records(ids[r0:r1], sizes[r0:r1],
+                                       weights[r0:r1],
+                                       tile_segments[t0:t1] - seg_cuts[d],
+                                       rec_bucket))
+        stacked = [np.stack([s[i] for s in shards]) for i in range(4)]
+        out = _score_pmap(seg_pad, table.has_knn)(
+            replicated_banks(table, n_dev), *stacked)
+    out = _fetch(out, rec_bucket)
     return np.concatenate([
         out[d, :seg_cuts[d + 1] - seg_cuts[d]] for d in range(n_dev)])

@@ -39,7 +39,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import batchcost, relax, synthesis
+from repro.core import batchcost, relax, spans, synthesis
 from repro.core.elements import DataStructureSpec
 from repro.core.hardware import HardwareProfile
 from repro.core.relax import RelaxTemplate, RelaxedDesign
@@ -246,7 +246,7 @@ def population_search(
         mixes=None,
         score_fn: Optional[Callable[
             [List[DataStructureSpec]], np.ndarray]] = None,
-        verify_oracle: bool = True) -> Dict[str, object]:
+        verify_oracle: bool = True, rid: int = 0) -> Dict[str, object]:
     """Evolve a population of relaxed designs under a designs budget.
 
     Each generation decodes the population to discrete chains, scores
@@ -258,6 +258,8 @@ def population_search(
     a multi-point axis, e.g. a read-fraction sweep).  ``score_fn``
     overrides the scoring call (the serving tier injects its
     deadline/fault-healing path); it must return one cost per spec.
+    Each generation's phases are leaf spans (:mod:`repro.core.spans`)
+    carrying ``rid``, the serving tier's request id of the search.
 
     Returns the ``design_beam``-shaped result dict (``design``,
     ``fanouts``, ``cost_s``, ``designs_costed``, ``elapsed_s``, ...)
@@ -285,24 +287,26 @@ def population_search(
     def score_population(pop: List[RelaxedDesign]
                          ) -> Tuple[List[float], bool]:
         """One engine call for the generation; True when budget ran dry."""
-        decoded = [relax.decode(d, f"gen{generation}_{i}")
-                   for i, d in enumerate(pop)]
-        fresh: List[DataStructureSpec] = []
-        fresh_chains = set()
-        for spec in decoded:
-            if spec.chain not in seen and spec.chain not in fresh_chains:
-                fresh.append(spec)
-                fresh_chains.add(spec.chain)
-        truncated = False
+        with spans.span(spans.SEARCH_DECODE, rid=rid):
+            decoded = [relax.decode(d, f"gen{generation}_{i}")
+                       for i, d in enumerate(pop)]
+            fresh: List[DataStructureSpec] = []
+            fresh_chains = set()
+            for spec in decoded:
+                if spec.chain not in seen and spec.chain not in fresh_chains:
+                    fresh.append(spec)
+                    fresh_chains.add(spec.chain)
+            truncated = False
+            if fresh:
+                try:
+                    grant = budget.charge(len(fresh))
+                except BudgetExhausted:
+                    grant = 0
+                truncated = grant < len(fresh)
+                fresh = fresh[:grant]
         if fresh:
-            try:
-                grant = budget.charge(len(fresh))
-            except BudgetExhausted:
-                grant = 0
-            truncated = grant < len(fresh)
-            fresh = fresh[:grant]
-        if fresh:
-            costs = score_fn(fresh)
+            with spans.span(spans.SEARCH_SCORE, rid=rid, designs=len(fresh)):
+                costs = score_fn(fresh)
             for spec, cost in zip(fresh, costs):
                 seen[spec.chain] = float(cost)
         fits = [seen.get(spec.chain, float("inf")) for spec in decoded]
@@ -335,44 +339,50 @@ def population_search(
             best_design = pop[ranked[0]]
             best_spec = relax.decode(best_design, "winner")
             if verify_oracle:
-                verified_cost = _verify_winner(
-                    best_spec, best_cost, points, hw)
+                with spans.span(spans.SEARCH_VERIFY, rid=rid):
+                    verified_cost = _verify_winner(
+                        best_spec, best_cost, points, hw)
         history.append(best_cost)
         if exhausted or budget.exhausted or generation == generations - 1:
             break
         # -- breed the next generation ------------------------------------
-        elite = []
-        for i in ranked:
-            if np.isfinite(fits[i]) and pop[i] not in elite:
-                elite.append(pop[i])
-            if len(elite) >= max(refine_top, 1):
-                break
-        # anneal the knob jitter on budget *spent*, not generation count:
-        # coarse structural exploration while designs are cheap, fine
-        # continuum exploitation (below any pow2 grid step) near the end
-        frac = budget.spent / budget.limit
-        sigma = mutation_sigma * (
-            min(FINE_SIGMA, mutation_sigma) / mutation_sigma) ** frac
-        children: List[RelaxedDesign] = list(elite[:2])   # elitism
+        with spans.span(spans.SEARCH_BREED, rid=rid):
+            elite = []
+            for i in ranked:
+                if np.isfinite(fits[i]) and pop[i] not in elite:
+                    elite.append(pop[i])
+                if len(elite) >= max(refine_top, 1):
+                    break
+            # anneal the knob jitter on budget *spent*, not generation
+            # count: coarse structural exploration while designs are
+            # cheap, fine continuum exploitation (below any pow2 grid
+            # step) near the end
+            frac = budget.spent / budget.limit
+            sigma = mutation_sigma * (
+                min(FINE_SIGMA, mutation_sigma) / mutation_sigma) ** frac
+            children: List[RelaxedDesign] = list(elite[:2])   # elitism
         for d in elite[:refine_top]:
             if refine_steps > 0:
-                children.append(relax.refine(
-                    d, hw, float(points[0][0].n_entries),
-                    read_fraction, steps=refine_steps))
-        for d in elite:                     # pure-knob local exploitation
-            children.append(mutate(rng, d, FINE_SIGMA, structural_p=0.0))
-        # one random immigrant keeps structural diversity from draining
-        children.append(random_design(
-            rng, templates[rng.randrange(len(templates))]))
-        while len(children) < population:
-            parent = _tournament(rng, pop, fits, tournament)
-            if rng.random() < crossover_rate:
-                other = _tournament(rng, pop, fits, tournament)
-                child = crossover(rng, parent, other)
-            else:
-                child = parent
-            children.append(mutate(rng, child, sigma))
-        pop = children[:population]
+                with spans.span(spans.SEARCH_REFINE, rid=rid):
+                    children.append(relax.refine(
+                        d, hw, float(points[0][0].n_entries),
+                        read_fraction, steps=refine_steps))
+        with spans.span(spans.SEARCH_BREED, rid=rid):
+            for d in elite:                 # pure-knob local exploitation
+                children.append(mutate(rng, d, FINE_SIGMA,
+                                       structural_p=0.0))
+            # one random immigrant keeps structural diversity from draining
+            children.append(random_design(
+                rng, templates[rng.randrange(len(templates))]))
+            while len(children) < population:
+                parent = _tournament(rng, pop, fits, tournament)
+                if rng.random() < crossover_rate:
+                    other = _tournament(rng, pop, fits, tournament)
+                    child = crossover(rng, parent, other)
+                else:
+                    child = parent
+                children.append(mutate(rng, child, sigma))
+            pop = children[:population]
 
     if best_spec is None:
         raise BudgetExhausted(

@@ -85,7 +85,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import devicecost, memo
+from repro.core import devicecost, memo, spans
 from repro.core.autocomplete import SearchResult, enumerate_frontier
 from repro.core.batchcost import (PackedFrontier, PackedSweep,
                                   concat_frontiers, concat_sweeps,
@@ -142,6 +142,9 @@ class ServiceStats:
     snapshot_restored: int = 0  # warm restarts that loaded entries
     snapshot_discarded: int = 0  # snapshots discarded (corrupt/stale/error)
     snapshot_corrupt: int = 0   # the unreadable subset of discarded
+    # -- tracing (see docs/serving.md "Tracing") -------------------------
+    queue_wait_s: float = 0.0   # summed submit-to-dequeue seconds
+    dequeued: int = 0           # requests taken into a batch
 
 
 @dataclasses.dataclass
@@ -175,12 +178,16 @@ class _Request:
     finalize: Callable[[float], object]   # elapsed-seconds -> answer
     future: Future
     t0: float
+    rid: int = 0                          # request id on every span
     lane: str = INTERACTIVE
     deadline: Optional[float] = None      # absolute time.monotonic()
     deadline_s: Optional[float] = None    # the relative deadline requested
     cost: float = 1.0                     # admission price in cells
     remaining: int = 0                    # evals not yet scored/errored
     dead: bool = False                    # expired/cancelled mid-batch
+
+    #: request ids, shared by searches and the requests they submit
+    ids = itertools.count(1)
 
 
 class _SessionState:
@@ -405,6 +412,7 @@ class DesignCalculatorService:
                     "discarded %s warm-restart snapshot at %s; "
                     "cold-starting", report.outcome, self._snapshot_path)
         self._sched.reopen()
+        spans.install_gc_span()
         self._thread = threading.Thread(target=self._supervise, daemon=True,
                                         name="design-calculator-serving")
         self._thread.start()
@@ -425,6 +433,7 @@ class DesignCalculatorService:
         if self._thread.is_alive():    # timed out; try again later
             return
         self._thread = None
+        spans.remove_gc_span()
         self._fail_pending()
 
     def _fail_pending(self) -> None:
@@ -486,12 +495,13 @@ class DesignCalculatorService:
             self._sessions.setdefault(name, _SessionState())
         return ServiceSession(self, name)
 
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, float]:
         with self._lock:
             out = dict(dataclasses.asdict(self._stats))
         out.update(self._shards.stats())
         for lane in self._sched.lanes:
             out[f"queued_{lane}"] = self._sched.depth(lane)
+        out.update(spans.counters())
         return out
 
     def health(self) -> Dict:
@@ -662,7 +672,8 @@ class DesignCalculatorService:
                      mixes=None,
                      session: Optional[str] = None,
                      deadline_s: Optional[float] = None,
-                     lane: Optional[str] = None) -> Future:
+                     lane: Optional[str] = None,
+                     parent: Optional[int] = None) -> Future:
         """A (designs x workloads) grid as one request.
 
         Sweeps over the same workload-point axis arriving in one
@@ -671,7 +682,8 @@ class DesignCalculatorService:
         group); the answer is a
         :class:`~repro.core.whatif.WorkloadSweepAnswer`.  Sweeps ride
         the bulk lane and pay their whole (designs x points) grid in
-        admission cells."""
+        admission cells.  ``parent`` is the request id of the search a
+        generation's sweep belongs to, recorded on its submit span."""
         hw_name = self._profile_name(hw)
         specs = tuple(specs)
         points = normalize_points(workloads, mixes)
@@ -686,7 +698,8 @@ class DesignCalculatorService:
                 np.asarray(ev.totals), elapsed)
         return self._submit([ev], finalize, session=session,
                             cost=request_cost(len(specs), len(points)),
-                            deadline_s=deadline_s, lane=lane or BULK)
+                            deadline_s=deadline_s, lane=lane or BULK,
+                            parent=parent)
 
     def submit_search(self, workload: Workload, hw,
                       mix: Optional[Dict[str, float]] = None, *,
@@ -743,6 +756,7 @@ class DesignCalculatorService:
         deadline = (time.monotonic() + deadline_s
                     if deadline_s is not None else None)
         sweep_lane = lane or BULK
+        rid = next(_Request.ids)
         fut: Future = Future()
 
         def score_fn(specs) -> np.ndarray:
@@ -759,7 +773,7 @@ class DesignCalculatorService:
             inner = self.submit_sweep(
                 [s for s in specs], [w for w, _ in points], hw_profile,
                 [dict(mi) for _, mi in points], session=None,
-                deadline_s=remaining, lane=sweep_lane)
+                deadline_s=remaining, lane=sweep_lane, parent=rid)
             # lint: untimed-wait(request deadline + supervisor bound the wait)
             answer = inner.result()
             return np.asarray(answer.totals, np.float64).mean(axis=0)
@@ -774,7 +788,7 @@ class DesignCalculatorService:
                     budget=SearchBudget(budget_designs),
                     workloads=wls,
                     mixes=mixes if mixes is not None else mix,
-                    score_fn=score_fn, **search_kwargs)
+                    score_fn=score_fn, rid=rid, **search_kwargs)
             except Exception as exc:    # noqa: BLE001 — future carries it
                 with self._lock:
                     self._stats.failed += 1
@@ -822,7 +836,20 @@ class DesignCalculatorService:
                 finalize: Callable[[float], object], *,
                 lane: str = INTERACTIVE, cost: float = 1.0,
                 session: Optional[str] = None,
-                deadline_s: Optional[float] = None) -> Future:
+                deadline_s: Optional[float] = None,
+                parent: Optional[int] = None) -> Future:
+        rid = next(_Request.ids)
+        ids = {"rid": rid, "lane": lane}
+        if parent is not None:
+            ids["parent"] = parent
+        with spans.span(spans.SUBMIT, **ids):
+            return self._admit(evals, finalize, rid, lane, cost, session,
+                               deadline_s)
+
+    def _admit(self, evals: List[_Evaluation],
+               finalize: Callable[[float], object], rid: int, lane: str,
+               cost: float, session: Optional[str],
+               deadline_s: Optional[float]) -> Future:
         thread = self._thread
         if thread is None or not thread.is_alive():
             raise RuntimeError("service is not running (call start())")
@@ -842,7 +869,7 @@ class DesignCalculatorService:
         deadline = (time.monotonic() + deadline_s
                     if deadline_s is not None else None)
         fut: Future = Future()
-        req = _Request(evals, finalize, fut, time.perf_counter(),
+        req = _Request(evals, finalize, fut, time.perf_counter(), rid=rid,
                        lane=lane, deadline=deadline, deadline_s=deadline_s,
                        cost=cost)
         try:
@@ -922,30 +949,8 @@ class DesignCalculatorService:
                 return
             if head is None:       # defensive: untimed get never times out
                 continue
-            batch = [head]
-            bulk_taken = 1 if head.lane == BULK else 0
-            closing = False
-            deadline = time.monotonic() + self._window
-            while len(batch) < self._max_batch:
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    break
-                allowed = None
-                if self._bulk_per_window is not None \
-                        and bulk_taken >= self._bulk_per_window:
-                    # this window's bulk share is spent: keep accepting
-                    # interactive arrivals only; queued bulk waits for
-                    # the next window
-                    allowed = (INTERACTIVE,)
-                nxt = self._sched.get(timeout=remaining, lanes=allowed)
-                if nxt is None:
-                    break
-                if nxt is CLOSED:
-                    closing = True
-                    break
-                if nxt.lane == BULK:
-                    bulk_taken += 1
-                batch.append(nxt)
+            with spans.span(spans.WINDOW, rid=head.rid):
+                batch, closing = self._gather(head)
             # in-flight tracking for the supervisor: a crash anywhere in
             # _serve_batch fails exactly this window's unresolved futures
             # with WorkerCrashed instead of hanging them (the old blanket
@@ -956,6 +961,33 @@ class DesignCalculatorService:
             if closing:
                 return
 
+    def _gather(self, head: _Request) -> Tuple[List[_Request], bool]:
+        """The coalescing window opened by ``head``: ``(batch, closing)``,
+        ``closing`` once the scheduler closed during the window."""
+        batch = [head]
+        bulk_taken = 1 if head.lane == BULK else 0
+        deadline = time.monotonic() + self._window
+        while len(batch) < self._max_batch:
+            remaining = deadline - time.monotonic()
+            if remaining <= 0:
+                break
+            allowed = None
+            if self._bulk_per_window is not None \
+                    and bulk_taken >= self._bulk_per_window:
+                # this window's bulk share is spent: keep accepting
+                # interactive arrivals only; queued bulk waits for
+                # the next window
+                allowed = (INTERACTIVE,)
+            nxt = self._sched.get(timeout=remaining, lanes=allowed)
+            if nxt is None:
+                break
+            if nxt is CLOSED:
+                return batch, True
+            if nxt.lane == BULK:
+                bulk_taken += 1
+            batch.append(nxt)
+        return batch, False
+
     def _pack(self, ev: _Evaluation) -> PackedFrontier:
         chains = tuple(s.chain for s in ev.specs)
         if ev.points is not None:
@@ -965,17 +997,18 @@ class DesignCalculatorService:
             key = (chains, ev.workload, mix_key)
         with self._lock:
             state = self._sessions.get(ev.session) if ev.session else None
-        if state is not None:
-            packed = state.get(key)
+        packed = state.get(key) if state is not None else None
+        with spans.span(spans.PACK, rid=ev.owner.rid if ev.owner else 0,
+                        designs=len(ev.specs), pinned=int(packed is not None)):
             if packed is not None:
                 with self._lock:
                     self._stats.session_frontier_hits += 1
                 return packed
-        if ev.points is not None:
-            packed = pack_sweep(ev.specs, [p[0] for p in ev.points],
-                                [dict(p[1]) for p in ev.points])
-        else:
-            packed = pack_frontier(ev.specs, ev.workload, ev.mix)
+            if ev.points is not None:
+                packed = pack_sweep(ev.specs, [p[0] for p in ev.points],
+                                    [dict(p[1]) for p in ev.points])
+            else:
+                packed = pack_frontier(ev.specs, ev.workload, ev.mix)
         if state is not None:
             state.put(key, packed)
         return packed
@@ -993,6 +1026,10 @@ class DesignCalculatorService:
 
     def _finalize(self, req: _Request) -> bool:
         """Resolve one fully-scored request; True on success."""
+        with spans.span(spans.FINALIZE, rid=req.rid):
+            return self._resolve(req)
+
+    def _resolve(self, req: _Request) -> bool:
         try:
             for ev in req.evals:
                 if ev.error is not None:
@@ -1036,11 +1073,15 @@ class DesignCalculatorService:
         ``None`` when every owner expired before a scoring call ran.
         """
         if points is not None:
-            product = concat_sweeps([ev.packed for ev in evals])
-            pool_call = self._shards.score_sweep
+            concat, pool_call = concat_sweeps, self._shards.score_sweep
         else:
-            product = concat_frontiers([ev.packed for ev in evals])
-            pool_call = self._shards.score_frontier
+            concat, pool_call = concat_frontiers, self._shards.score_frontier
+        if len(evals) == 1:     # nothing to splice: the product is its own
+            product = concat([evals[0].packed])
+        else:
+            with spans.span(spans.SPLICE, rid=evals[0].owner.rid,
+                            n=len(evals)):
+                product = concat([ev.packed for ev in evals])
 
         def finish(result, engine: str, used: int = 1) -> Tuple[int, int]:
             offset = 0
@@ -1156,6 +1197,10 @@ class DesignCalculatorService:
         # fault seam: a rule on "service.worker" crashes the loop here,
         # exercising the supervisor's restart + WorkerCrashed path
         faults.check("service.worker", len(batch))
+        taken = time.perf_counter()
+        with self._lock:
+            self._stats.queue_wait_s += sum(taken - req.t0 for req in batch)
+            self._stats.dequeued += len(batch)
         groups: Dict[Tuple, List[_Evaluation]] = {}
         live: List[_Request] = []
         now = time.monotonic()

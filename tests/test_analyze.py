@@ -58,6 +58,15 @@ def test_locks_silent_on_clean_twin_and_honors_suppression():
     assert _run("locks", "locks/clean.py") == []
 
 
+@pytest.mark.parametrize("fixture,flagged", [("spans_bad.py", 2),
+                                              ("spans_clean.py", 0)])
+def test_locks_guards_the_span_table(fixture, flagged):
+    findings = _run("locks", f"locks/{fixture}")
+    assert len(findings) == flagged
+    assert all("_TOTALS" in f.message and "_LOCK" in f.message
+               for f in findings)
+
+
 # -- futures ----------------------------------------------------------------
 
 def test_futures_fires_on_seeded_fixture():

@@ -11,7 +11,7 @@ this checker makes it mechanical:
 * the guarded module globals (``memo.REGISTRY``; devicecost's interning
   tables and shard-threshold state, writes only — their unlocked reads
   are deliberate CPython-safe fast paths) must be accessed under
-  ``MEMO_LOCK``.
+  ``MEMO_LOCK``; the span table ``spans._TOTALS`` under ``spans._LOCK``.
 
 ``__init__`` is exempt (no concurrent aliases exist yet).  A genuinely
 safe unlocked access carries ``# lint: unlocked(<reason>)`` — the
@@ -69,6 +69,9 @@ GUARDED_GLOBALS: Dict[str, Dict] = {
     "_SHARD_STATE": {"owner": "repro.core.devicecost",
                      "locks": _MEMO_LOCKS, "writes_only": True,
                      "dotted": {"devicecost._SHARD_STATE"}},
+    "_TOTALS": {"owner": "repro.core.spans",
+                "locks": {"_LOCK", "spans._LOCK"}, "writes_only": False,
+                "dotted": {"spans._TOTALS"}},
 }
 
 #: container-method calls that mutate the receiver
@@ -183,7 +186,8 @@ def _check_globals(mod: ModuleRecord,
         kind = "write of" if _is_write(node, parents) else "read of"
         yield Finding(
             mod.relpath, node.lineno, NAME, "unlocked",
-            f"{kind} guarded global {name} outside 'with MEMO_LOCK:'")
+            f"{kind} guarded global {name} outside "
+            f"'with {sorted(cfg['locks'])[0]}:'")
 
 
 def check_module(mod: ModuleRecord) -> Iterable[Finding]:
