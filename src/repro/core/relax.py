@@ -222,15 +222,15 @@ def _surrogate_rows() -> Dict[str, int]:
     return {name: devicecost.model_id(name) for name in _SURROGATE_MODELS}
 
 
-@functools.lru_cache(maxsize=512)
-def _surrogate_fn(template: RelaxTemplate):
-    """The jitted ``(cost, grad_theta)`` function of one template.
+def _cost_fn(template: RelaxTemplate):
+    """The surrogate's per-query cost ``cost(theta, banks, n_entries,
+    read_fraction, value_bytes)`` of one template, untraced.
 
     The template's level structure is baked in statically (a bounded set
     of templates appears in any search run, so the compile set is
     bounded); banks, data size and read fraction stay traced inputs —
-    a hardware swap reuses the compiled surrogate exactly like the fused
-    scorer reuses its executable.
+    a hardware swap reuses every compiled function built from it exactly
+    like the fused scorer reuses its executable.
     """
     rows = _surrogate_rows()
     levels = template.levels
@@ -297,7 +297,13 @@ def _surrogate_fn(template: RelaxTemplate):
             with_knn=False)
         return (jnp.stack(weights) * y).sum()
 
-    return jax.jit(jax.value_and_grad(cost))
+    return cost
+
+
+@functools.lru_cache(maxsize=512)
+def _surrogate_fn(template: RelaxTemplate):
+    """The jitted ``(cost, grad_theta)`` function of one template."""
+    return jax.jit(jax.value_and_grad(_cost_fn(template)))
 
 
 def surrogate_cost(design: RelaxedDesign, hw: HardwareProfile,
@@ -326,6 +332,51 @@ class _RefineConfig:
     b2: float = 0.95
 
 
+#: traced-function entry counter of :func:`_refine_fn` — increments only
+#: while jax (re)traces a refinement loop, i.e. once per compiled
+#: (template, steps, learning rate); tests probe it to assert that repeat
+#: refinements and hardware swaps never recompile
+_REFINE_TRACES = [0]
+
+
+def refine_trace_count() -> int:
+    return _REFINE_TRACES[0]
+
+
+@functools.lru_cache(maxsize=512)
+def _refine_fn(template: RelaxTemplate, steps: int, learning_rate: float):
+    """The jitted ``(theta, banks, n_entries, read_fraction, value_bytes)
+    -> theta`` refinement loop of one template: ``steps`` AdamW steps on
+    the surrogate, each projected back into the template's knob bounds
+    (baked in as constants).  A ``fori_loop``, so the compile stays the
+    size of one step's."""
+    grad_fn = jax.grad(_cost_fn(template))
+    run = _RefineConfig(learning_rate=learning_rate)
+    lo, hi = template.knob_bounds()
+
+    def loop(theta, banks, n_entries, read_fraction, value_bytes):
+        _REFINE_TRACES[0] += 1
+
+        def step(_, carry):
+            params, state = carry
+            grad = grad_fn(params["theta"], banks, n_entries,
+                           read_fraction, value_bytes)
+            updates, state = adamw_update({"theta": grad}, state, params,
+                                          run)
+            params = apply_updates(params, updates)
+            params = {"theta": jnp.clip(params["theta"],
+                                        jnp.asarray(lo, jnp.float32),
+                                        jnp.asarray(hi, jnp.float32))}
+            return params, state
+
+        params = {"theta": theta}
+        params, _ = jax.lax.fori_loop(0, steps, step,
+                                      (params, adamw_init(params)))
+        return params["theta"]
+
+    return jax.jit(loop)
+
+
 def refine(design: RelaxedDesign, hw: HardwareProfile,
            n_entries: float, read_fraction: float = 1.0,
            value_bytes: float = 8.0, steps: int = 8,
@@ -336,27 +387,18 @@ def refine(design: RelaxedDesign, hw: HardwareProfile,
     scores the discrete result with the real fused engine — gradients
     only ever *propose*.  Knobs are projected back into the template's
     log2 bounds after every step, so the optimizer cannot escape the
-    decodable continuum.
+    decodable continuum.  The whole loop is one compiled call per
+    (template, steps, learning rate): one dispatch, one fetch.
     """
-    grad_fn = _surrogate_fn(design.template)
-    banks = devicecost.device_table(hw).banks
-    lo, hi = design.template.knob_bounds()
-    params = {"theta": jnp.asarray(design.theta, jnp.float32)}
-    state = adamw_init(params)
-    run = _RefineConfig(learning_rate=learning_rate)
-    n = jnp.asarray(float(n_entries), jnp.float32)
-    r = jnp.asarray(float(read_fraction), jnp.float32)
-    vb = jnp.asarray(float(value_bytes), jnp.float32)
-    for _ in range(max(int(steps), 1)):
-        _, grad = grad_fn(params["theta"], banks, n, r, vb)
-        updates, state = adamw_update({"theta": grad}, state, params, run)
-        params = apply_updates(params, updates)
-        params = {"theta": jnp.clip(params["theta"],
-                                    jnp.asarray(lo, jnp.float32),
-                                    jnp.asarray(hi, jnp.float32))}
+    fn = _refine_fn(design.template, max(int(steps), 1),
+                    float(learning_rate))
+    theta = fn(np.asarray(design.theta, np.float32),
+               devicecost.device_table(hw).banks,
+               np.float32(n_entries), np.float32(read_fraction),
+               np.float32(value_bytes))
     return RelaxedDesign(design.template,
-                         tuple(float(v) for v in np.asarray(
-                             params["theta"], np.float64)))
+                         tuple(float(v) for v in np.asarray(theta,
+                                                            np.float64)))
 
 
 def read_fraction_of(mix: Optional[Dict[str, float]],

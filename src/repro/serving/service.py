@@ -85,7 +85,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import devicecost, memo, spans
+from repro.core import devicecost, memo, relax, spans
 from repro.core.autocomplete import SearchResult, enumerate_frontier
 from repro.core.batchcost import (PackedFrontier, PackedSweep,
                                   concat_frontiers, concat_sweeps,
@@ -502,6 +502,7 @@ class DesignCalculatorService:
         for lane in self._sched.lanes:
             out[f"queued_{lane}"] = self._sched.depth(lane)
         out.update(spans.counters())
+        out["refine_traces"] = relax.refine_trace_count()
         return out
 
     def health(self) -> Dict:

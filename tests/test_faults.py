@@ -161,7 +161,16 @@ def test_hung_part_times_out_and_is_abandoned():
         with plan.activate():
             t0 = time.monotonic()
             totals, _ = pool.score_frontier(packed, hw)
-        assert time.monotonic() - t0 < 0.45   # did not wait out the hang
+            elapsed = time.monotonic() - t0
+            # the abandoned part wakes after the hang and still scores:
+            # keep this plan active until it has, so that its fused call
+            # cannot land in (and use up) a later test's fault plan
+            deadline = time.monotonic() + 10.0
+            while plan.occurrences("devicecost.fused") < 2 and \
+                    time.monotonic() < deadline:
+                time.sleep(0.01)
+        assert elapsed < 0.45   # did not wait out the hang
+        assert plan.occurrences("devicecost.fused") == 2
         assert np.allclose(totals, baseline, rtol=1e-6)
         stats = pool.stats()
         assert stats["shard_timeouts"] == 1

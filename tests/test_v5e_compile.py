@@ -119,3 +119,17 @@ def test_kernel_compiles_to_mosaic_for_v5e(one_chip, case):
     fn, args = case(one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_refine_loop_compiles_for_v5e(one_chip):
+    """The search's compiled refinement loop, on the template with the
+    most surrogate terms (a bloom-filtered hash over a B+ level)."""
+    from repro.core import relax
+    template = relax.RelaxTemplate(("Hash", "B+", "ODP"), bloom=True)
+    banks = {k: _spec(one_chip, v.shape, v.dtype)
+             for k, v in devicecost.build_table(hw1()).banks.items()}
+    scalar = _spec(one_chip, (), jnp.float32)
+    compiled = relax._refine_fn(template, 2, 0.35).lower(
+        _spec(one_chip, (template.n_knobs,), jnp.float32), banks,
+        scalar, scalar, scalar).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES
