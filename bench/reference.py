@@ -9,8 +9,11 @@ It imports nothing of the program under test and takes nothing the
 program made: designs arrive as plain :class:`Level` tuples, workloads
 and hardware profiles as the numbers in a configuration file.
 
-Only the operations the benchmark's traffic sends are defined (``get``
-and ``update``); any other operation raises.
+``get`` and ``update`` are defined here.  Any other operation is
+defined by a file of its own, ``bench/reference_ops/<op>.py``, whose
+``records(design, wl)`` builds the operation's records from this
+module's helpers (:func:`geometry`, :func:`get_records`); an operation
+with neither raises.
 
 ``dtype`` selects the precision the records are priced and summed in:
 ``float64`` is the reference; ``bfloat16`` (via ``ml_dtypes``) is the
@@ -21,7 +24,10 @@ both, as the program computes it on the host.
 from __future__ import annotations
 
 import functools
+import importlib.util
 import math
+import os
+import re
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -30,6 +36,8 @@ PTR_BYTES = 8
 FENCE_BYTES = 8
 #: size arguments are clipped into the analytical models' fitted range
 X_LO, X_HI = 1.0, 1e12
+#: the calculator's range when a configuration states no scan length
+DEFAULT_SELECTIVITY = 0.001
 
 
 class Level(NamedTuple):
@@ -56,6 +64,8 @@ class Workload(NamedTuple):
     key_bytes: int
     value_bytes: int
     zipf_alpha: float
+    #: the share of the key space one range operation reads
+    selectivity: float = DEFAULT_SELECTIVITY
 
 
 # -- the element library: how each element partitions and lays out ---------
@@ -221,13 +231,36 @@ def get_records(design: Design, wl: Workload) -> List[Record]:
     return recs
 
 
+OPS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "reference_ops")
+
+
+@functools.lru_cache(maxsize=None)
+def _op_file(op: str):
+    """The ``records`` function of ``reference_ops/<op>.py``, or None."""
+    if not re.fullmatch(r"[A-Za-z0-9_]+", op):
+        return None
+    path = os.path.join(OPS_DIR, f"{op}.py")
+    if not os.path.isfile(path):
+        return None
+    spec = importlib.util.spec_from_file_location(
+        "bench_reference_op_" + op, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.records
+
+
 def op_records(op: str, design: Design, wl: Workload) -> List[Record]:
     if op == "get":
         return get_records(design, wl)
     if op == "update":          # a point query, then one value write
         return get_records(design, wl) + [
             ("serial_write", max(float(wl.value_bytes), 1.0), 1.0)]
-    raise ValueError(f"the reference defines get and update, not {op!r}")
+    records = _op_file(op)
+    if records is None:
+        raise ValueError(f"the reference defines get and update, and no "
+                         f"reference_ops/{op}.py defines {op!r}")
+    return records(design, wl)
 
 
 def mix_records(design: Design, wl: Workload,

@@ -95,15 +95,17 @@ def enable_compile_cache() -> str:
 
 def execute(bench: Dict, cell: Dict, seed: int, seconds: float,
             trace_on: bool, devices: List, peaks: Optional[Dict],
-            params: Optional[Dict] = None) -> Dict:
+            params: Optional[Dict] = None,
+            config: Optional[Dict] = None) -> Dict:
     """Set up, measure and check one run of ``cell`` on ``devices``;
-    returns the result object (``checks`` last).  ``params`` replaces
-    the traffic file's parameters (the tests run cells at small sizes)."""
+    returns the result object (``checks`` last).  ``params`` and
+    ``config`` replace the traffic and configuration files (the tests run
+    cells at small sizes, and on configurations of their own)."""
     import jax
     from bench.system import System
     if trace_on and devices[0].platform != "tpu":
         raise RuntimeError("device metrics come only from a TPU trace")
-    config = tr.load_config(cell["config"])
+    config = config or tr.load_config(cell["config"])
     params = params or tr.load_traffic(cell["traffic"])
     chips = int(cell["chips"])
     clock = CompileClock()

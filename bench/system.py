@@ -48,7 +48,7 @@ class System:
             program = ProgramWorkload(
                 n_entries=wl.n_entries, n_queries=wl.n_queries,
                 key_bytes=wl.key_bytes, value_bytes=wl.value_bytes,
-                zipf_alpha=wl.zipf_alpha)
+                zipf_alpha=wl.zipf_alpha, selectivity=wl.selectivity)
             self._workloads[wl] = program
         return program
 

@@ -13,7 +13,8 @@ Every traffic mix is a data file ``bench/traffic/<name>.json`` whose
 ``closed_sweep``
     ``clients`` callers, each submitting fresh (designs x workload-point)
     sweeps back to back: ``designs`` designs drawn per sweep, over the
-    update shares and Zipf constants of the point axis.
+    shares of one operation (``op_share``; ``update_share`` is the share
+    of ``update``) and the Zipf constants of the point axis.
 ``closed_search``
     Back-to-back design searches, one caller; the run searches the fixed
     list of ``searches`` search seeds, in an order the seed permutes, so
@@ -32,7 +33,7 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 import numpy as np
 
 from bench.designs import Completions, Families, design_source
-from bench.reference import Design, Workload
+from bench.reference import DEFAULT_SELECTIVITY, Design, Workload
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -56,10 +57,26 @@ def rng_for(seed: int, *stream: int) -> np.random.Generator:
                                                          *stream]))
 
 
+def selectivity(config: Dict) -> float:
+    """The share of the records one scan reads, from YCSB's own keys
+    ``maxscanlength`` and ``scanlengthdistribution``: the mean scan over
+    ``recordcount``.  A configuration that states no scan length takes
+    the calculator's default range."""
+    if "maxscanlength" not in config and \
+            "scanlengthdistribution" not in config:
+        return DEFAULT_SELECTIVITY
+    dist = config.get("scanlengthdistribution")
+    if dist != "uniform":
+        raise ValueError(f"scanlengthdistribution {dist!r}: only uniform "
+                         f"scan lengths are drawn")
+    mean = (1 + int(config["maxscanlength"])) / 2.0
+    return mean / int(config["recordcount"])
+
+
 def base_workload(config: Dict) -> Workload:
     return Workload(int(config["recordcount"]), int(config["n_queries"]),
                     int(config["key_bytes"]), int(config["value_bytes"]),
-                    float(config["zipf_alpha"]))
+                    float(config["zipf_alpha"]), selectivity(config))
 
 
 def config_mix(config: Dict) -> Dict[str, float]:
@@ -147,19 +164,45 @@ def whatif_questions(config: Dict, params: Dict, seed: int,
 
 
 # -- closed-loop sweeps -------------------------------------------------------
+def op_share(params: Dict) -> Dict:
+    """The swept share: ``op_share``, or ``update_share`` read as the
+    share of ``update``."""
+    if "op_share" in params:
+        if "update_share" in params:
+            raise ValueError("a sweep gives op_share or update_share, "
+                             "not both")
+        return params["op_share"]
+    return dict(params["update_share"], op="update")
+
+
+def share_mix(config: Dict, op: str, u: float) -> Dict[str, float]:
+    """The configuration's operations with ``op`` at share ``u``: ``op``
+    weighs ``ops x u``, and every other operation keeps its own
+    proportion of ``ops x (1 - u)``."""
+    mix = config["operations"]
+    ops = float(sum(mix.values()))
+    rest = float(sum(v for k, v in mix.items() if k != op))
+    if rest <= 0.0:
+        raise ValueError(f"no operation but {op!r} to take the rest of "
+                         f"the share")
+    out = {k: ops * (1.0 - u) * (float(v) / rest)
+           for k, v in mix.items() if k != op}
+    out[op] = ops * u
+    return {k: out[k] for k in dict.fromkeys([*mix, op])}
+
+
 def sweep_points(config: Dict, params: Dict
                  ) -> List[Tuple[Workload, Dict[str, float]]]:
-    """The point axis: each Zipf constant x each update share."""
-    share = params["update_share"]
+    """The point axis: each Zipf constant x each share of the swept
+    operation."""
+    share = op_share(params)
     shares = np.linspace(float(share["from"]), float(share["to"]),
                          int(share["steps"]))
-    ops = float(sum(config["operations"].values()))
     points = []
     for alpha in params["zipf_alphas"]:
         wl = base_workload(config)._replace(zipf_alpha=float(alpha))
         for u in shares:
-            points.append((wl, {"get": ops * (1.0 - float(u)),
-                                "update": ops * float(u)}))
+            points.append((wl, share_mix(config, share["op"], float(u))))
     return points
 
 
