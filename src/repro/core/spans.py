@@ -15,13 +15,17 @@ search's inner ``SUBMIT`` inside ``SEARCH_SCORE``, and ``GC``, which
 interrupts whatever runs, excepted); causes link by ``rid``, not by
 nesting.  With no profiler running a span costs the annotation's check,
 two clock reads and one locked add: a few microseconds.
+
+Beside the spans, :func:`add_records` counts the access records the
+fused packer emits per operation, which a service's ``stats()`` carries
+as ``records_n.<op>`` (:func:`record_counters`).
 """
 from __future__ import annotations
 
 import gc
 import threading
 import time
-from typing import Dict, Tuple
+from typing import Dict, Iterable, Tuple
 
 from jax.profiler import TraceAnnotation
 
@@ -56,6 +60,9 @@ NAMES = (SUBMIT, WINDOW, PACK, SPLICE, DISPATCH, FETCH, FINALIZE,
 _LOCK = threading.Lock()
 #: name -> [seconds, count]
 _TOTALS: Dict[str, list] = {name: [0.0, 0] for name in NAMES if name != GC}
+#: operation -> record slots the fused packer emitted with a nonzero
+#: count, summed over workload points (``templatecost.emit_operation``)
+_RECORDS: Dict[str, int] = {}
 #: [seconds, count] of GC, written only by the collector's callback:
 #: collections never overlap, and a callback taking ``_LOCK`` could
 #: deadlock against a span that triggered the collection inside it
@@ -105,6 +112,19 @@ def counters() -> Dict[str, float]:
         out[f"span_s.{name}"] = seconds
         out[f"span_n.{name}"] = count
     return out
+
+
+def add_records(op: str, n: int) -> None:
+    """Count ``n`` records emitted for one operation: one locked add."""
+    with _LOCK:
+        _RECORDS[op] = _RECORDS.get(op, 0) + n
+
+
+def record_counters(ops: Iterable[str]) -> Dict[str, int]:
+    """``records_n.<op>`` for every operation of ``ops``, zero until
+    first emitted."""
+    with _LOCK:
+        return {f"records_n.{op}": _RECORDS.get(op, 0) for op in ops}
 
 
 #: the collection in progress, as (annotation, start); install refcount

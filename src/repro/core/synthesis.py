@@ -348,6 +348,13 @@ def _random_access(cb: CostBreakdown, level: LevelInfo, workload: Workload,
 # ---------------------------------------------------------------------------
 def synthesize_get(spec: DataStructureSpec, workload: Workload
                    ) -> CostBreakdown:
+    return _point_walk(spec, workload, miss=False)
+
+
+def _point_walk(spec: DataStructureSpec, workload: Workload, miss: bool
+                ) -> CostBreakdown:
+    """The point lookup's walk; with ``miss`` the key is absent (a put's
+    search): an unsorted leaf is scanned whole and no value is fetched."""
     cb = CostBreakdown()
     inst = instantiate(spec, workload)
     for level in inst.levels[:-1]:
@@ -414,12 +421,17 @@ def synthesize_get(spec: DataStructureSpec, workload: Workload
     if el.sorted_keys:
         cb.add(access.SORTED_SEARCH, entries * workload.key_bytes,
                layout=layout, note="leaf search")
+    elif miss:
+        # a miss reads every key of the page
+        cb.records.append(AccessRecord(
+            access.SCAN, access.resolve(access.SCAN, layout=layout),
+            entries * workload.key_bytes, 1.0, "leaf scan"))
     else:
         # expected half scan on a hit
         cb.records.append(AccessRecord(
             access.SCAN, access.resolve(access.SCAN, layout=layout),
             entries * workload.key_bytes / 2, 1.0, "leaf scan"))
-    if layout != "row-wise" and el.retains_values:
+    if layout != "row-wise" and el.retains_values and not miss:
         cb.add(access.RANDOM_ACCESS, entries * workload.value_bytes,
                note="value fetch")
     return cb
@@ -487,11 +499,69 @@ def synthesize_update(spec: DataStructureSpec, workload: Workload
     return cb
 
 
+def split_entry_bytes(cls: int, fanout: float) -> float:
+    """Bytes a put's node split writes into a parent of emission class
+    ``cls``: a sorted node shifts half its fences and pointers; a page
+    list links the new page in; any other class sets one pointer."""
+    if cls in (CLS_DEP, CLS_DEP_BLOOM):
+        return (fanout - 1) / 2 * (FENCE_BYTES + PTR_BYTES)
+    if cls in (CLS_LL, CLS_SKIP):
+        return 2.0 * PTR_BYTES
+    return float(PTR_BYTES)
+
+
+def synthesize_put(spec: DataStructureSpec, workload: Workload
+                   ) -> CostBreakdown:
+    """An insert of a new key (YCSB's insert), into the structure as
+    instantiated for the workload (the structure is not grown).
+
+    1. The point walk with the workload's Zipf constant taken as 0 (new
+       keys land uniformly) and the leaf search a miss, no value fetch.
+    2. The in-page write: an ordered page shifts its upper half
+       (``E / 2`` pairs), an unordered page appends one pair.
+    3. Amortised splits: a page of capacity ``C`` splits once every
+       ``C / 2`` puts (count ``s = 2 / C``) and writes the moved half,
+       ``C / 2`` pairs.  Its parent takes one entry with count ``s``
+       (:func:`split_entry_bytes`); a parent with sorted fences of
+       fanout ``F`` then splits with count ``s x 2 / F``, writing
+       ``F / 2`` fences and pointers, and the cascade carries on to its
+       own parent.  Any other parent class stops it.  A root split adds
+       no level; CSB+ splits like B+ (its node group is not copied); a
+       leaf bloom filter's bit writes are not priced.
+    """
+    cb = _point_walk(spec, dataclasses.replace(workload, zipf_alpha=0.0),
+                     miss=True)
+    inst = instantiate(spec, workload)
+    term = inst.terminal
+    pair = workload.pair_bytes
+    if term.element.sorted_keys:
+        entries = max(term.entries_per_node, 1.0)
+        cb.add(access.SERIAL_WRITE, entries / 2 * pair, note="shift half")
+    else:
+        cb.add(access.SERIAL_WRITE, pair, note="append")
+    cap = term.element.capacity or 256
+    count = 2.0 / cap
+    cb.add(access.SERIAL_WRITE, cap / 2 * pair, count=count,
+           note="page split")
+    for level in reversed(inst.levels[:-1]):
+        cls = element_class(level.element)
+        fanout = level.element.fanout or 2
+        cb.add(access.SERIAL_WRITE, split_entry_bytes(cls, fanout),
+               count=count, note=f"{level.element.name} new entry")
+        if cls not in (CLS_DEP, CLS_DEP_BLOOM):
+            break
+        count = count * 2 / fanout
+        cb.add(access.SERIAL_WRITE, fanout / 2 * (FENCE_BYTES + PTR_BYTES),
+               count=count, note=f"{level.element.name} split")
+    return cb
+
+
 OPERATIONS = {
     "get": synthesize_get,
     "range_get": synthesize_range_get,
     "bulk_load": synthesize_bulk_load,
     "update": synthesize_update,
+    "put": synthesize_put,
 }
 
 
@@ -539,7 +609,7 @@ def symbolic_breakdown(op: str, template: Tuple
     classes, (sorted_, bloom, layout, value_fetch, _area) = template
     p_rec = (access.RANDOM_ACCESS, access.resolve(access.RANDOM_ACCESS))
     recs: List[Tuple[str, str]] = []
-    if op in ("get", "range_get", "update"):
+    if op in ("get", "range_get", "update", "put"):
         for cls in classes:
             if cls == CLS_SKIP:
                 recs.append((access.SORTED_SEARCH,
@@ -571,9 +641,21 @@ def symbolic_breakdown(op: str, template: Tuple
         else:
             recs.append((access.SCAN, access.resolve(access.SCAN,
                                                      layout=layout)))
-        if value_fetch:
+        if value_fetch and op != "put":
             recs.append(p_rec)
-        if op == "range_get":
+        if op == "put":
+            # in-page write, page split, then one new-entry slot per
+            # internal level from the deepest up (and a node-split slot
+            # where it has sorted fences); count 0 above where the
+            # cascade stops
+            w_rec = (access.SERIAL_WRITE,
+                     access.resolve(access.SERIAL_WRITE))
+            recs += [w_rec, w_rec]
+            for cls in reversed(classes):
+                recs.append(w_rec)
+                if cls in (CLS_DEP, CLS_DEP_BLOOM):
+                    recs.append(w_rec)
+        elif op == "range_get":
             recs += [p_rec, (access.SCAN, access.resolve(
                 access.SCAN, layout=layout, op="range"))]
         elif op == "update":

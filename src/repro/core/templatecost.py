@@ -72,7 +72,7 @@ import numpy as np
 from repro.core import access
 from repro.core.devicecost import TILE, model_id
 from repro.core.elements import Element
-from repro.core import memo
+from repro.core import memo, spans
 from repro.core.memo import MEMO_LOCK, DictCache
 from repro.core.synthesis import (CLS_APPEND, CLS_DEP, CLS_DEP_BLOOM,
                                   CLS_IND, CLS_IND_FUNC, CLS_LL, CLS_SKIP,
@@ -600,7 +600,10 @@ class _Rows:
                 np.concatenate([p[4] for p in self.parts], axis=1))
 
 
-def _emit_get(t: _Tables, wc: _WorkloadCols, rows: _Rows) -> None:
+def _emit_get(t: _Tables, wc: _WorkloadCols, rows: _Rows,
+              miss: bool = False) -> None:
+    """The point lookup's records; with ``miss`` a put's search (an
+    unsorted leaf scanned whole, no value fetch)."""
     kb = wc.key_bytes[:, None]
     # -- internal levels ----------------------------------------------------
     m = t.cls >= CLS_IND_FUNC                 # every class with its own P
@@ -652,7 +655,9 @@ def _emit_get(t: _Tables, wc: _WorkloadCols, rows: _Rows) -> None:
               np.maximum(entries[:, m] * kb, 1.0))
     m = ~t.c_t_sorted
     rows.emit(ch[m], base[m] + 2, t.c_mid_scan[m],
-              entries[:, m] * kb / 2.0)
+              entries[:, m] * kb if miss else entries[:, m] * kb / 2.0)
+    if miss:
+        return
     m = t.c_t_value_fetch
     rows.emit(ch[m], base[m] + 3, _mid(access.RANDOM_ACCESS),
               np.maximum(entries[:, m] * wc.value_bytes[:, None], 1.0))
@@ -671,6 +676,53 @@ def _emit_tail_range(t: _Tables, wc: _WorkloadCols, rows: _Rows) -> None:
     rows.emit(ch, base + 1, t.c_mid_rscan,
               np.maximum(wc.c_t_epn, 1.0) * wc.key_bytes[:, None],
               n_pages)
+
+
+def _unskewed(wc: _WorkloadCols) -> _WorkloadCols:
+    """The same columns with every workload's Zipf constant taken as 0."""
+    return dataclasses.replace(wc, workloads=tuple(
+        dataclasses.replace(w, zipf_alpha=0.0) for w in wc.workloads))
+
+
+def _emit_tail_put(t: _Tables, wc: _WorkloadCols, rows: _Rows) -> None:
+    """A put's writes after its search (``synthesis.synthesize_put``): the
+    in-page write, the page split, then per internal level from the
+    deepest up a new entry and, for sorted fences, a node split.  Split
+    counts are structural; a level above where the cascade stopped
+    carries count 0."""
+    ch = np.arange(len(t.c_n_int))
+    base = (t.c_n_int + 1) * _SLOTS
+    w_mid = _mid(access.SERIAL_WRITE)
+    pair = wc.pair_bytes[:, None]
+    in_page = np.where(t.c_t_sorted[None, :],
+                       np.maximum(wc.c_t_epn, 1.0) / 2.0 * pair, pair)
+    rows.emit(ch, base, w_mid, np.maximum(in_page, 1.0))
+    count = 2.0 / t.c_termcap
+    rows.emit(ch, base + 1, w_mid,
+              np.maximum(t.c_termcap[None, :] / 2.0 * pair, 1.0), count)
+    if not len(t.ch):
+        return
+    fanout = np.where(t.fanout > 0, t.fanout, 2.0)
+    dep = (t.cls == CLS_DEP) | (t.cls == CLS_DEP_BLOOM)
+    entry = np.where(dep, (fanout - 1.0) / 2.0 * (FENCE_BYTES + PTR_BYTES),
+                     np.where((t.cls == CLS_LL) | (t.cls == CLS_SKIP),
+                              2.0 * PTR_BYTES, float(PTR_BYTES)))
+    up = t.c_n_int[t.ch] - 1 - t.lvl          # 0 for the terminal's parent
+    entry_n = np.zeros(len(t.ch))
+    split_n = np.zeros(len(t.ch))
+    carried = count.copy()                    # rows keeps a view of count
+    for k in range(int(up.max()) + 1):        # one row per chain at a time
+        r = np.flatnonzero(up == k)
+        c = t.ch[r]
+        entry_n[r] = carried[c]
+        split = np.where(dep[r], carried[c] * 2.0 / fanout[r], 0.0)
+        split_n[r] = split
+        carried[c] = split
+    order = base[t.ch] + 2 + 2 * up
+    rows.emit(t.ch, order, w_mid, np.maximum(entry, 1.0), entry_n)
+    rows.emit(t.ch[dep], order[dep] + 1, w_mid,
+              np.maximum(fanout[dep] / 2.0 * (FENCE_BYTES + PTR_BYTES),
+                         1.0), split_n[dep])
 
 
 def _emit_bulk_load(t: _Tables, wc: _WorkloadCols, rows: _Rows) -> None:
@@ -722,9 +774,14 @@ def emit_operation(op: str, t: _Tables, wc: _WorkloadCols
                                   (rows.W, len(ch))))
     elif op == "bulk_load":
         _emit_bulk_load(t, wc, rows)
+    elif op == "put":
+        _emit_get(t, _unskewed(wc), rows, miss=True)
+        _emit_tail_put(t, wc, rows)
     else:
         raise KeyError(op)
-    return rows.collect()
+    out = rows.collect()
+    spans.add_records(op, int(np.count_nonzero(out[4])))
+    return out
 
 
 # ---------------------------------------------------------------------------

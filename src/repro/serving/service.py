@@ -93,7 +93,7 @@ from repro.core.batchcost import (PackedFrontier, PackedSweep,
                                   pack_sweep)
 from repro.core.elements import DataStructureSpec, Element
 from repro.core.hardware import HardwareProfile
-from repro.core.synthesis import Workload
+from repro.core.synthesis import OPERATIONS, Workload
 from repro.core.whatif import (WhatIfAnswer, WorkloadSweepAnswer,
                                question_design, question_hardware,
                                question_sweep, question_workload)
@@ -502,6 +502,7 @@ class DesignCalculatorService:
         for lane in self._sched.lanes:
             out[f"queued_{lane}"] = self._sched.depth(lane)
         out.update(spans.counters())
+        out.update(spans.record_counters(OPERATIONS))
         out["refine_traces"] = relax.refine_trace_count()
         return out
 

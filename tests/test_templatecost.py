@@ -20,7 +20,7 @@ from repro.core.batchcost import (compile_breakdown, concat_frontiers,
 from repro.core.devicecost import model_id
 from repro.core.synthesis import Workload, cost_workload
 
-OPS = ("get", "range_get", "update", "bulk_load")
+OPS = ("get", "range_get", "update", "bulk_load", "put")
 
 
 def _grid_specs():
