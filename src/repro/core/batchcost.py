@@ -44,9 +44,10 @@ Public API
 ``pack_sweep(specs, workloads, mixes)`` / ``cost_sweep(...)``
     The **workload-sweep engine** (PR 5): a (designs x workloads) grid —
     a read/write-ratio, skew, selectivity or data-size continuum — packed
-    by splicing the shared workload-free template statics with
+    whole as a grid over the shared workload-free template statics with
     per-workload geometry columns (:func:`repro.core.templatecost.
-    pack_points`) and scored in ONE fused call
+    pack_grid`: each point's frontier is a row of its group's buffers)
+    and scored in ONE fused call
     (:func:`repro.core.devicecost.score_sweep`: bank gathers issued once
     for all workloads).  ``cost_sweep`` returns the ``[W, D]`` totals
     grid; hardware stays a pure parameter-table swap.
@@ -88,7 +89,8 @@ synthesis code at all; the full memo map lives in
 3. The *segment cache* here: each spec's mix-weighted, tile-padded
    (ids, sizes, weights) arrays per (chain, workload, mix) — populated in
    batch by the vectorized packer, reused record-for-record by later
-   frontiers containing the same chain.
+   frontiers containing the same chain.  ``pack_frontier`` only: a sweep
+   packs as a grid and cuts, looks up and stores no segment.
 4. The *frontier cache*: whole packed frontiers per (chains, workload,
    mix) — and the *sweep cache*: whole (designs x workloads) grids per
    (chains, points) — the steady-state what-if-serving hit paths.
@@ -103,7 +105,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import devicecost, memo as memo_module, templatecost
+from repro.core import devicecost, memo as memo_module, spans, templatecost
 from repro.core.devicecost import _MODEL_NAMES, model_id as _model_id
 from repro.core.elements import DataStructureSpec, Element
 from repro.core.hardware import HardwareProfile
@@ -527,7 +529,8 @@ class PackedSweep:
     When the grid is *rectangular* — every point shares the record layout
     (same template statics; the common case for read/write-ratio, skew,
     selectivity or query-count sweeps at a fixed data size) — the frozen
-    per-point frontiers share one interned ids array, and ``score``
+    per-point frontiers share one ids array (``pack_sweep`` packs them as
+    one grid), and ``score``
     issues ONE :func:`repro.core.devicecost.score_sweep` call whose bank
     gathers are amortized across every workload.  Non-rectangular sweeps
     (``n_entries`` changing a chain's level structure) degrade gracefully
@@ -688,14 +691,19 @@ def pack_sweep(specs: Sequence[DataStructureSpec],
     """Pack a (designs x workloads) grid, splicing shared template
     statics with per-workload geometry columns.
 
-    Incremental like :func:`pack_frontier`: per-(spec, point) segments
-    come from the segment cache when present; only genuinely new
-    (chain, point) cells reach the workload-axis packer
-    (:func:`repro.core.templatecost.pack_points` — statics and record
-    layout computed once per structural group, numerics batched over the
-    workload axis).  Each point's frontier also lands in the frontier
-    cache, so a later single-workload ``cost_many`` against any sweep
-    point is a pure cache hit — and vice versa.  A repeated identical
+    The grid path: the points are grouped by (op sequence, depth
+    signature) and each group is packed whole over the designs in the
+    caller's order (:func:`repro.core.templatecost.pack_grid` — statics
+    and record layout computed once per group, numerics batched over the
+    workload axis).  Each point's frontier takes its sizes and weights
+    as read-only row views of its group's buffers, and every point of a
+    group shares one ids and one tile-segments array, so ``rectangular``
+    holds by identity.  No per-(design, point) segment is cut, looked up
+    or cached: a sweep brings fresh designs, which a per-chain cache
+    would only churn (``pack_frontier`` keeps that cache for the few
+    chains what-if questions re-ask).  Each point's frontier still lands
+    in the frontier cache, so a later single-workload ``cost_many``
+    against any sweep point is a pure cache hit.  A repeated identical
     sweep is one sweep-cache hit.
     """
     points = normalize_points(workloads, mixes)
@@ -705,41 +713,18 @@ def pack_sweep(specs: Sequence[DataStructureSpec],
     cached = _sweep_cache.get(sweep_key)
     if cached is not None:
         return cached
-    per_point: List[List[Optional[Tuple[np.ndarray, ...]]]] = []
-    #: missing-point pattern -> ordered unique chains missing exactly there
-    missing: Dict[Tuple[int, ...], List[Tuple[Element, ...]]] = {}
-    missing_pts: Dict[Tuple[Element, ...], List[int]] = {}
-    for pi, (workload, mix_items) in enumerate(points):
-        row: List[Optional[Tuple[np.ndarray, ...]]] = []
-        for chain in chains:
-            seg = _segment_cache.get((chain, workload, mix_items))
-            row.append(seg)
-            if seg is None:
-                pts = missing_pts.setdefault(chain, [])
-                if not pts or pts[-1] != pi:   # dedupe repeated chains
-                    pts.append(pi)
-        per_point.append(row)
-    for chain, pts in missing_pts.items():
-        missing.setdefault(tuple(pts), []).append(chain)
-    # only genuinely new (chain, point) cells reach the packer: chains
-    # already cached for SOME points re-pack only the points they miss
-    for pts, group_chains in missing.items():
-        packed = templatecost.pack_points(
-            group_chains, [points[pi] for pi in pts])
-        pos_of = {chain: i for i, chain in enumerate(group_chains)}
-        for li, pi in enumerate(pts):
-            workload, mix_items = points[pi]
-            for ci, chain in enumerate(chains):
-                if per_point[pi][ci] is None and chain in pos_of:
-                    seg = packed[li][pos_of[chain]]
-                    _segment_cache.put((chain, workload, mix_items), seg)
-                    per_point[pi][ci] = seg
-    frontiers = []
-    for (workload, mix_items), row in zip(points, per_point):
-        frontier = _assemble_frontier(row)
-        if chains:
+    if not chains:
+        frontiers = [_assemble_frontier([])] * len(points)
+    else:
+        frontiers = [None] * len(points)
+        for grid in templatecost.pack_grid(chains, points):
+            for row, pi in enumerate(grid.points):
+                frontiers[pi] = PackedFrontier(
+                    grid.ids, grid.sizes[row], grid.weights[row],
+                    grid.tile_segments, len(chains))
+        spans.add_grid_cells(len(chains) * len(points))
+        for (workload, mix_items), frontier in zip(points, frontiers):
             _frontier_cache.put((chains, workload, mix_items), frontier)
-        frontiers.append(frontier)
     sweep = PackedSweep(points, len(specs), tuple(frontiers))
     _sweep_cache.put(sweep_key, sweep)
     return sweep

@@ -14,8 +14,8 @@ Three invariants the loop maintains:
 
 * **Survivors are never re-packed.**  A ``seen`` memo maps decoded
   chains to their scored cost; only genuinely new chains reach
-  ``cost_sweep``, and those hit the incremental ``pack_frontier``
-  segment memos for any structurally-shared levels.  After warmup the
+  ``cost_sweep``, which packs them as one (design x point) grid over
+  the cached template statics.  After warmup the
   generation loop triggers zero recompiles (pow2 shape bucketing in
   :mod:`repro.core.devicecost`).
 * **Budgets are designs-costed.**  A :class:`SearchBudget` counts every

@@ -18,7 +18,9 @@ two clock reads and one locked add: a few microseconds.
 
 Beside the spans, :func:`add_records` counts the access records the
 fused packer emits per operation, which a service's ``stats()`` carries
-as ``records_n.<op>`` (:func:`record_counters`).
+as ``records_n.<op>`` (:func:`record_counters`), and
+:func:`add_grid_cells` counts the (design, point) cells a sweep packs as
+one grid, carried as ``pack_grid_cells`` (:func:`grid_cells`).
 """
 from __future__ import annotations
 
@@ -63,6 +65,9 @@ _TOTALS: Dict[str, list] = {name: [0.0, 0] for name in NAMES if name != GC}
 #: operation -> record slots the fused packer emitted with a nonzero
 #: count, summed over workload points (``templatecost.emit_operation``)
 _RECORDS: Dict[str, int] = {}
+#: (design, point) cells packed by the sweep grid path
+#: (``batchcost.pack_sweep``)
+_GRID_CELLS = [0]
 #: [seconds, count] of GC, written only by the collector's callback:
 #: collections never overlap, and a callback taking ``_LOCK`` could
 #: deadlock against a span that triggered the collection inside it
@@ -125,6 +130,19 @@ def record_counters(ops: Iterable[str]) -> Dict[str, int]:
     first emitted."""
     with _LOCK:
         return {f"records_n.{op}": _RECORDS.get(op, 0) for op in ops}
+
+
+def add_grid_cells(n: int) -> None:
+    """Count ``n`` (design, point) cells packed as a grid: one locked
+    add."""
+    with _LOCK:
+        _GRID_CELLS[0] += n
+
+
+def grid_cells() -> int:
+    """The (design, point) cells packed as a grid so far."""
+    with _LOCK:
+        return _GRID_CELLS[0]
 
 
 #: the collection in progress, as (annotation, start); install refcount

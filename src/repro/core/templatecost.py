@@ -38,12 +38,17 @@ The pipeline is then:
    are emitted as ``[W, records]`` column ops over *emission-class
    masks*.  Records a chain's scalar synthesis would *not* emit (e.g.
    linked-list page hops when one page is visited) carry count 0.
-3. **Assembly** (:func:`pack_points`): one argsort orders records by
+3. **Assembly** (:func:`pack_grid`): one argsort orders records by
    (chain, op, level, slot) — the order key is structural, so a single
    argsort serves every workload — and a vectorized scatter pads each
-   design's block to a ``devicecost.TILE`` multiple.  The per-chain
-   model-id arrays are interned on ``(template, ops)``: all workloads
-   (and all chains sharing a template) reference the *same* ids array.
+   design's block to a ``devicecost.TILE`` multiple, into one
+   ``[W, records]`` buffer per group of points (:class:`GroupGrid`):
+   the sweep packer's product, whose rows are the points' frontiers.
+4. **Segments** (:func:`pack_points`): the grid cut into per-chain
+   copies for the segment cache of ``batchcost.pack_frontier``.  The
+   per-chain model-id arrays are interned on ``(template, ops)``: all
+   workloads (and all chains sharing a template) reference the *same*
+   ids array.
 
 :func:`pack_specs` is the single-workload wrapper
 (``pack_points(chains, one point)``), keeping the PR-3 API for
@@ -785,7 +790,8 @@ def emit_operation(op: str, t: _Tables, wc: _WorkloadCols
 
 
 # ---------------------------------------------------------------------------
-# Assembly: per-spec tile-padded segments, for every sweep point at once
+# Assembly: the tile-padded (chain x point) grid, and per-spec segments cut
+# from it
 # ---------------------------------------------------------------------------
 #: (template, ops) -> interned per-chain model-id array — workload-free:
 #: every workload of a sweep (and every chain sharing a template)
@@ -823,13 +829,33 @@ def _intern_segment_ids(template: Tuple, ops: Tuple[str, ...],
     return ids
 
 
+@dataclasses.dataclass(frozen=True)
+class GroupGrid:
+    """One (op sequence, depth signature) group of sweep points, packed
+    whole: every chain's tile-padded record block, in the caller's chain
+    order (duplicates included), for every point of the group.
+
+    Each row of ``sizes`` / ``weights`` is one point's whole frontier;
+    ``ids`` and ``tile_segments`` are shared by every point of the group.
+    All four arrays are read-only.
+    """
+
+    points: Tuple[int, ...]      # the group's sweep-point indices
+    ids: np.ndarray              # int32 [total]
+    tile_segments: np.ndarray    # int64 [total // TILE]: chain per tile
+    sizes: np.ndarray            # float64 [len(points), total]
+    weights: np.ndarray          # float64 [len(points), total]
+    offsets: np.ndarray          # int64 [chains + 1]: padded block bounds
+
+
 def _pack_group(chains: Sequence[Tuple[Element, ...]],
                 points: Sequence[Tuple[Workload, Tuple]],
-                ops: Tuple[str, ...], pidx: List[int],
-                out: List[List]) -> None:
+                ops: Tuple[str, ...], pidx: List[int]
+                ) -> Tuple[GroupGrid, List[ChainStatics]]:
     """Pack one (op sequence, structural signature) group of sweep points:
     statics and the argsorted record layout are computed once; sizes and
-    weights carry the group's workload axis."""
+    weights carry the group's workload axis.  Returns the grid and each
+    chain's statics."""
     n_chains = len(chains)
     workloads = [points[pi][0] for pi in pidx]
     statics_list = [chain_statics(c, workloads[0].n_entries)
@@ -889,17 +915,48 @@ def _pack_group(chains: Sequence[Tuple[Element, ...]],
     out_ids[pos_idx] = mids
     out_sizes[:, pos_idx] = sizes
     out_weights[:, pos_idx] = weights
-    # per-chain segments are COPIES, not views: cached segments outlive
-    # this call (batchcost's segment cache), and a view would pin the
-    # whole group's [W, total] buffers alive for as long as any one
-    # small chain stays cached
-    for c, st in enumerate(statics_list):
-        sl = slice(int(pad_off[c]), int(pad_off[c + 1]))
-        ids_c = _intern_segment_ids(st.template, ops,
-                                    _frozen(out_ids[sl]))
-        for wi, pi in enumerate(pidx):
-            out[pi][c] = (ids_c, _frozen(out_sizes[wi, sl]),
-                          _frozen(out_weights[wi, sl]))
+    tile_segments = np.repeat(np.arange(n_chains, dtype=np.int64),
+                              padded // TILE)
+    for arr in (out_ids, out_sizes, out_weights, tile_segments):
+        arr.setflags(write=False)
+    grid = GroupGrid(tuple(pidx), out_ids, tile_segments, out_sizes,
+                     out_weights, pad_off.astype(np.int64))
+    return grid, statics_list
+
+
+def _group_points(chains: Sequence[Tuple[Element, ...]],
+                  points: Sequence[Tuple[Workload, Tuple]]
+                  ) -> Dict[Tuple[Tuple[str, ...], int], List[int]]:
+    """Sweep-point indices grouped by (op sequence, joint depth
+    signature); the signature is derived once per distinct ``n_entries``
+    and keyed by its index, so no point hashes a per-chain tuple."""
+    sig_index: Dict[Tuple, int] = {}
+    by_entries: Dict[int, int] = {}
+    groups: Dict[Tuple[Tuple[str, ...], int], List[int]] = {}
+    for pi, (workload, mix_items) in enumerate(points):
+        n = workload.n_entries
+        sig = by_entries.get(n)
+        if sig is None:
+            depths = tuple(_expansion_depths(chain, n) for chain in chains)
+            sig = by_entries[n] = sig_index.setdefault(depths,
+                                                       len(sig_index))
+        ops = tuple(op for op, _ in mix_items)
+        groups.setdefault((ops, sig), []).append(pi)
+    return groups
+
+
+def pack_grid(chains: Sequence[Tuple[Element, ...]],
+              points: Sequence[Tuple[Workload, Tuple]]
+              ) -> List[GroupGrid]:
+    """The whole (chain x point) grid, one :class:`GroupGrid` per group of
+    points sharing an op sequence and a depth signature — the sweep
+    packer's product.  No per-chain segment is cut and nothing is cached
+    per chain: a point's frontier is a row of its group's buffers."""
+    points = tuple(points)
+    if not chains or not points:
+        return []
+    return [_pack_group(chains, points, ops, pidx)[0]
+            for (ops, _), pidx in _group_points(chains, points).items()]
 
 
 def pack_points(chains: Sequence[Tuple[Element, ...]],
@@ -914,21 +971,28 @@ def pack_points(chains: Sequence[Tuple[Element, ...]],
     packed as ONE group — statics, emission layout and the argsort are
     computed once, and all numeric columns are evaluated with a workload
     axis.  Points whose ``n_entries`` changes a chain's expansion depths
-    simply land in their own group.
+    simply land in their own group.  The group's grid is then cut into
+    per-chain segments.
     """
     n_chains = len(chains)
     points = tuple(points)
     out: List[List] = [[None] * n_chains for _ in points]
     if n_chains == 0 or not points:
         return out
-    groups: Dict[Tuple, List[int]] = {}
-    for pi, (workload, mix_items) in enumerate(points):
-        ops = tuple(op for op, _ in mix_items)
-        sig = tuple(_expansion_depths(chain, workload.n_entries)
-                    for chain in chains)
-        groups.setdefault((ops, sig), []).append(pi)
-    for (ops, _), pidx in groups.items():
-        _pack_group(chains, points, ops, pidx, out)
+    for (ops, _), pidx in _group_points(chains, points).items():
+        grid, statics_list = _pack_group(chains, points, ops, pidx)
+        off = grid.offsets
+        # per-chain segments are COPIES, not views: cached segments
+        # outlive this call (batchcost's segment cache), and a view would
+        # pin the whole group's [W, total] buffers alive for as long as
+        # any one small chain stays cached
+        for c, st in enumerate(statics_list):
+            sl = slice(int(off[c]), int(off[c + 1]))
+            ids_c = _intern_segment_ids(st.template, ops,
+                                        _frozen(grid.ids[sl]))
+            for wi, pi in enumerate(pidx):
+                out[pi][c] = (ids_c, _frozen(grid.sizes[wi, sl]),
+                              _frozen(grid.weights[wi, sl]))
     return out
 
 

@@ -503,6 +503,7 @@ class DesignCalculatorService:
             out[f"queued_{lane}"] = self._sched.depth(lane)
         out.update(spans.counters())
         out.update(spans.record_counters(OPERATIONS))
+        out["pack_grid_cells"] = spans.grid_cells()
         out["refine_traces"] = relax.refine_trace_count()
         return out
 

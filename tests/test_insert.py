@@ -241,7 +241,9 @@ def test_other_operations_emit_what_they_emitted_before_puts():
 
 def test_records_counter_in_stats():
     """``stats()`` carries ``records_n.<op>`` for every operation, and
-    packing a put mix adds its nonzero record slots times points."""
+    packing a put mix adds its nonzero record slots times points; it
+    carries ``pack_grid_cells`` too, which the sweep adds designs x
+    points to."""
     from repro.serving import DesignCalculatorService
     hw = analytical_profile("HW3", **HW3)
     chain = (el.btree_internal(16), el.btree_internal(8),
@@ -257,3 +259,4 @@ def test_records_counter_in_stats():
     # page access and its key search) and 6 writes, at each of 2 points
     assert after["records_n.put"] - before["records_n.put"] == 2 * 12
     assert after["records_n.get"] == before["records_n.get"]
+    assert after["pack_grid_cells"] - before["pack_grid_cells"] == 1 * 2

@@ -135,51 +135,118 @@ def test_repeat_sweeps_zero_recompiles_and_pure_cache_hits(hw_analytical):
 
 def test_sweep_statics_shared_across_workloads(hw_analytical):
     """The PR-5 cache-key refactor, observable: packing one chain set
-    under many same-structure workloads resolves template statics ONCE,
-    and every point's segment references the *same* interned model-id
-    array (only the numeric sizes/weights columns are per-workload)."""
+    under many same-structure workloads resolves template statics ONCE
+    per chain, and every point's frontier references the *same* ids
+    array (only the numeric sizes/weights rows are per-workload).  A
+    later ``pack_frontier`` at a sweep point scores equal to the
+    sweep's row."""
     batchcost.clear_caches()
     workloads, _ = _axis()
     # one op set across all points (the read/write-ratio axis), so every
-    # point shares one (template, ops) interning entry per chain
+    # point lands in one (ops, depth signature) group
     mixes = whatif.read_fraction_mixes((1.0, 0.8, 0.6, 0.4, 0.2))
     specs = [el.spec_btree(), el.spec_hash_table(), el.spec_skip_list()]
     sweep = pack_sweep(specs, workloads, mixes)
     info = batchcost.cache_info()
     # one statics entry per distinct chain — NOT per (chain, workload)
     assert info["chain_statics"].currsize == len(specs)
-    points = normalize_points(workloads, mixes)
-    for ci, spec in enumerate(specs):
-        segs = [batchcost._segment_cache.get(
-            (spec.chain, w, mix_items)) for w, mix_items in points]
-        assert all(s is not None for s in segs)
-        ids0 = segs[0][0]
-        for seg in segs[1:]:
-            assert seg[0] is ids0, "per-workload segments must share " \
-                "one interned ids array"
-    # a later single-point pack_frontier reuses the sweep's segments
-    before = batchcost.cache_info()["packed_spec"].misses
+    ids0 = sweep.frontiers[0].ids
+    for f in sweep.frontiers[1:]:
+        assert f.ids is ids0, "every point must share one ids array"
+        assert f.tile_segments is sweep.frontiers[0].tile_segments
+    assert sweep.rectangular
+    # a later single-point pack_frontier, packed afresh through the
+    # per-chain segment path, scores equal to the sweep's row
+    batchcost.clear_caches()
     packed = batchcost.pack_frontier(specs, workloads[1], mixes[1])
-    assert batchcost.cache_info()["packed_spec"].misses == before
+    np.testing.assert_array_equal(packed.score(hw_analytical,
+                                               engine="grouped"),
+                                  sweep.score(hw_analytical,
+                                              engine="grouped")[1])
     np.testing.assert_allclose(packed.score(hw_analytical),
                                sweep.score(hw_analytical)[1], rtol=1e-6)
 
 
 def test_sweep_repacks_only_missing_points(hw_analytical):
-    """Sweeps and single-point calls feed each other: a sweep over a
-    point already warmed by ``cost_many`` re-packs ONLY the cells it is
-    actually missing (one new segment per chain per new point)."""
+    """Sweeps and single-point calls agree: a sweep over a point already
+    warmed by ``cost_many`` packs that point's row bit for bit as
+    ``cost_many`` packed it, without looking up the per-chain segment
+    cache."""
     batchcost.clear_caches()
     w1 = BASE
     w2 = dataclasses.replace(BASE, zipf_alpha=0.9)
     specs = [el.spec_btree(), el.spec_trie()]
-    row1 = cost_many(specs, w1, hw_analytical)   # warms (chain, w1) cells
-    before = batchcost.cache_info()["packed_spec"].misses
-    grid = cost_sweep(specs, [w1, w2], hw_analytical)
-    after = batchcost.cache_info()["packed_spec"].misses
-    # exactly the (chain, w2) cells were missing — w1 cells were hits
-    assert after == before + len(specs)
-    np.testing.assert_allclose(grid[0], row1, rtol=1e-6)
+    row1 = cost_many(specs, w1, hw_analytical, engine="grouped")
+    warm = batchcost.pack_frontier(specs, w1)
+    lookups = batchcost.cache_info()["packed_spec"]
+    sweep = pack_sweep(specs, [w1, w2])
+    info = batchcost.cache_info()["packed_spec"]
+    assert (info.hits, info.misses) == (lookups.hits, lookups.misses)
+    row = sweep.frontiers[0]
+    for name in ("ids", "sizes", "weights", "tile_segments"):
+        np.testing.assert_array_equal(getattr(row, name),
+                                      getattr(warm, name))
+    assert row.n_segments == warm.n_segments
+    np.testing.assert_array_equal(
+        sweep.score(hw_analytical, engine="grouped")[0], row1)
+
+
+def _grid_cases():
+    """(specs, workloads, mixes) per case of the grid-path test."""
+    designs = _frontier()[:24]
+    skewed = [dataclasses.replace(BASE, zipf_alpha=a)
+              for a in (0.0, 0.5, 0.99)]
+    small, big = Workload(n_entries=10_000), Workload(n_entries=4_000_000)
+    return {
+        "get_update": (designs, skewed,
+                       whatif.read_fraction_mixes((0.95, 0.5, 0.05))),
+        "range_put": (designs, skewed,
+                      [{"range_get": 95.0, "put": 5.0},
+                       {"range_get": 70.0, "put": 30.0},
+                       {"range_get": 50.0, "put": 50.0}]),
+        "duplicates": (designs[:6] + designs[:3] + [designs[4]] * 3,
+                       skewed[:2], None),
+        "two_depths": (designs[:10],
+                       [small, big, dataclasses.replace(small,
+                                                        zipf_alpha=0.9),
+                        dataclasses.replace(big, zipf_alpha=0.9)],
+                       {"get": 60.0, "update": 40.0}),
+    }
+
+
+@pytest.mark.parametrize("case", ["get_update", "range_put", "duplicates",
+                                  "two_depths"])
+def test_grid_sweep_equals_stacked_pack_frontier(case):
+    """The grid path packs every point's frontier bit for bit as a cold
+    per-point ``pack_frontier`` does (ids, sizes, weights, tile
+    segments); the points of one group share one ids array; it counts
+    designs x points grid cells and never looks up the per-chain segment
+    cache."""
+    from repro.core import spans
+    specs, workloads, mixes = _grid_cases()[case]
+    batchcost.clear_caches()
+    cells = spans.grid_cells()
+    sweep = pack_sweep(specs, workloads, mixes)
+    assert spans.grid_cells() - cells == len(specs) * len(workloads)
+    info = batchcost.cache_info()["packed_spec"]
+    assert info.hits + info.misses == 0
+    points = normalize_points(workloads, mixes)
+    by_ids = {}
+    for pi, (w, mix_items) in enumerate(points):
+        row = sweep.frontiers[pi]
+        assert not row.sizes.flags.writeable
+        by_ids.setdefault(id(row.ids), []).append(w.n_entries)
+        batchcost.clear_caches()
+        cold = batchcost.pack_frontier(specs, w, dict(mix_items))
+        for name in ("ids", "sizes", "weights", "tile_segments"):
+            np.testing.assert_array_equal(getattr(row, name),
+                                          getattr(cold, name))
+        assert row.n_segments == cold.n_segments == len(specs)
+    # one ids object per (ops, depth signature) group
+    groups = {w.n_entries for w, _ in points}
+    assert len(by_ids) == len(groups)
+    assert all(len(set(sizes)) == 1 for sizes in by_ids.values())
+    assert sweep.rectangular == (len(groups) == 1)
 
 
 def test_sweep_pad_rows_reference_real_model_ids(hw_analytical):
