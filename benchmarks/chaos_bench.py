@@ -218,7 +218,7 @@ def _compile_ladder(hws, max_records: int) -> None:
                 weights = np.zeros(bucket, np.float32)
                 tiles = np.zeros(bucket // devicecost.TILE, np.int64)
                 devicecost.score_frontier(ids, sizes, weights, tiles,
-                                          n_seg, hw, shard=False)
+                                          n_seg, hw)
                 devicecost.score_frontier(ids, sizes, weights, tiles,
                                           n_seg, hw, device=dev)
                 bucket *= 2

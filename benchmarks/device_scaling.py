@@ -1,9 +1,10 @@
-"""Device-scaling measurements: sharded sweep scoring at 1 vs N devices.
+"""Device-scaling measurements: sweep scoring at 1 vs N devices.
 
 On an accelerator the process's own devices are the measurement: the
-1-device side is the flat ``shard=False`` path (or a one-shard service)
-and the N-device side shards across every local device, both in this
-process — it already holds the chips, so a child could not reach them.
+1-device side is the flat ``score`` call (or a one-shard service) and
+the N-device side is the scoring-shard pool
+(:class:`repro.serving.ScoringShardPool`) over every local device, both
+in this process — it already holds the chips, so a child could not reach them.
 On the CPU, JAX pins its device list at backend init, so the two device
 counts run in *child* processes launched under
 ``--xla_force_host_platform_device_count=N`` (see
@@ -11,8 +12,8 @@ counts run in *child* processes launched under
 JSON line; the parent computes the scaling ratios:
 
 * ``--child sweep``   — steady-state sweep-grid scoring (cells/sec) on a
-  >= 4096-cell workload x design grid, flat jit vs the sharded pmap path
-  (parity asserted bit-for-bit before timing);
+  >= 4096-cell workload x design grid, the flat jit call vs the shard
+  pool (parity asserted bit-for-bit before timing);
 * ``--child serving`` — questions/sec through a
   ``DesignCalculatorService`` whose coalescing worker routes windows
   across the scoring-shard pool.
@@ -26,7 +27,7 @@ asserts the bar only where both hold and otherwise records an explicit
 waiver string in the emitted row, so the measured numbers still land in
 the BENCH trajectory without pretending the bar was met or moving it.
 
-``run(smoke=True)`` is the in-process sharded-parity pass wired into
+``run(smoke=True)`` is the in-process pool-parity pass wired into
 ``benchmarks/run.py --smoke``: no subprocesses, no timing bars.
 """
 from __future__ import annotations
@@ -90,6 +91,7 @@ def _child_sweep(quick: bool) -> Dict:
     import jax
     from repro.core import batchcost
     from repro.core.hardware import hw3
+    from repro.serving import ScoringShardPool
 
     hw = hw3()
     n_designs, n_points = (512, 8) if quick else (1024, 8)
@@ -97,12 +99,16 @@ def _child_sweep(quick: bool) -> Dict:
     sweep = batchcost.pack_sweep(frontier, workloads, mixes)
     cells = n_designs * n_points
 
-    flat = sweep.score(hw, shard=False)
-    sharded = sweep.score(hw, shard=True)
-    assert np.array_equal(sharded, flat), \
-        "sharded sweep diverged from the flat jit path"
-    flat_s = _steady_state(lambda: sweep.score(hw, shard=False))
-    sharded_s = _steady_state(lambda: sweep.score(hw, shard=True))
+    flat = sweep.score(hw)
+    pool = ScoringShardPool(min_cells_per_shard=1)
+    try:
+        pooled, _ = pool.score_sweep(sweep, hw)
+        assert np.array_equal(pooled, flat), \
+            "the shard pool's sweep diverged from the flat jit path"
+        flat_s = _steady_state(lambda: sweep.score(hw))
+        sharded_s = _steady_state(lambda: pool.score_sweep(sweep, hw))
+    finally:
+        pool.close()
     return {
         "devices": jax.device_count(),
         "platform": jax.default_backend(),
@@ -181,7 +187,7 @@ def _measure(mode: str, quick: bool) -> Tuple[Dict, Dict]:
         return (_run_child(mode, 1, quick),
                 _run_child(mode, BAR_DEVICES, quick))
     if mode == "sweep":
-        both = _child_sweep(quick)    # flat vs sharded, one process
+        both = _child_sweep(quick)    # flat vs the pool, one process
         return both, both
     return _child_serving(quick, shards=1), _child_serving(quick)
 
@@ -247,9 +253,9 @@ def serving_scaling_row(quick: bool = False) -> Dict:
 
 
 def _smoke() -> None:
-    """In-process sharded-parity pass (the ``run.py --smoke`` hook):
-    shard=True must be bit-identical to the flat jit path at whatever
-    device count this process has, pool merge included."""
+    """In-process pool-parity pass (the ``run.py --smoke`` hook): the
+    shard pool's merged sweep and frontier must be bit-identical to the
+    flat jit call at whatever device count this process has."""
     import numpy as np
 
     import jax
@@ -260,21 +266,19 @@ def _smoke() -> None:
     hw = hw3()
     frontier, workloads, mixes = _sweep_inputs(64, 4)
     sweep = batchcost.pack_sweep(frontier, workloads, mixes)
-    flat = sweep.score(hw, shard=False)
-    assert np.array_equal(sweep.score(hw, shard=True), flat), \
-        "sharded sweep diverged from the flat jit path"
+    flat = sweep.score(hw)
     packed = sweep.frontiers[0]
-    assert np.array_equal(packed.score(hw, shard=True),
-                          packed.score(hw, shard=False)), \
-        "sharded frontier scoring diverged from the flat jit path"
     pool = ScoringShardPool(min_cells_per_shard=1)
     try:
         pooled, used = pool.score_sweep(sweep, hw)
         assert used >= 1 and np.array_equal(pooled, flat), \
             "shard-pool merge diverged from the flat grid"
+        totals, _ = pool.score_frontier(packed, hw)
+        assert np.array_equal(totals, packed.score(hw)), \
+            "shard-pool frontier merge diverged from the flat call"
     finally:
         pool.close()
-    print(f"device-scaling smoke: sharded parity ok "
+    print(f"device-scaling smoke: pool parity ok "
           f"({jax.device_count()} device(s), {used} pool shard(s))")
 
 

@@ -1,7 +1,7 @@
 """Chip smoke test: the served what-if path on a TPU.
 
     python chip_smoke.py               # one chip: service phases + kernels
-    python chip_smoke.py --four-chips  # sharded sweep scoring across chips
+    python chip_smoke.py --four-chips  # the shard pool's sweep across chips
 
 One chip.  A ``DesignCalculatorService`` on the analytical profiles
 HW1-HW3 answers, phase by phase:
@@ -23,9 +23,9 @@ Then the four access-primitive Pallas kernels compile to Mosaic
 their oracles exactly.
 
 Four chips.  Only the multi-device path and what it is compared with:
-the sweep of (b) scored by the pmap path (``shard=True``) must equal the
-flat one-device call bit for bit, and a service with one scoring shard
-per chip must answer it with parts on more than one chip.
+the sweep of (b) scored by the flat one-device call must agree with the
+scalar oracle, and a service with one scoring shard per chip must answer
+it bit for bit equal to the flat call, with parts on more than one chip.
 
 Every phase prints its compile seconds, records scored and wall
 seconds.  The last line of stdout is one JSON object,
@@ -359,7 +359,7 @@ def kernels(clock: CompileClock, interpret: bool = False) -> None:
 
 
 # ---------------------------------------------------------------------------
-# four chips: sharded sweep scoring vs the flat call
+# four chips: the shard pool's sweep vs the flat call
 # ---------------------------------------------------------------------------
 def four_chips(clock: CompileClock) -> None:
     import jax
@@ -377,18 +377,8 @@ def four_chips(clock: CompileClock) -> None:
     cells = sweep.n_points * len(sweep.frontiers[0].ids)
     for rep in ("cold", "warm"):
         with Phase(clock, f"flat 1 device ({rep})") as ph:
-            flat = sweep.score(hw, shard=False)
+            flat = sweep.score(hw)
             ph.records = cells
-        with Phase(clock, f"pmap {n_dev} devices ({rep})") as ph:
-            check(sweep._sharded_arrays(True) is not None,
-                  "the sweep does not fit the pmap path")
-            sharded = sweep.score(hw, shard=True)
-            ph.records = cells
-    same = bool(np.array_equal(sharded, flat))
-    print(f"sharded == flat bit for bit: {same} "
-          f"(max abs diff {float(np.max(np.abs(sharded - flat))):.3e})",
-          flush=True)
-    check(same, "the sharded sweep differs from the flat call")
     rng = np.random.default_rng(0)
     worst = max(rel_err(flat[i, j], cost_workload(frontier[j], workloads[i],
                                                   hw, mixes[i]))

@@ -13,8 +13,9 @@ whole frontiers through one of two engines:
 * ``engine="fused"`` (default): the packed frontier arrays go to
   :func:`repro.core.devicecost.score_frontier` — **one** jitted JAX call
   evaluating every record against device-resident parameter banks and
-  reducing with a single ``segment_sum`` (sharded across devices for big
-  frontiers).
+  reducing with a single ``segment_sum`` (the serving tier's
+  :class:`repro.serving.shards.ScoringShardPool` splits big products
+  across devices).
 * ``engine="grouped"``: the PR-1 reference oracle — group records of all
   candidates by model and evaluate each Level-2 model's vectorized
   :meth:`FittedModel.predict` once per call (~14 predictions per
@@ -387,15 +388,15 @@ class PackedFrontier:
             for d in range(n_parts)]
 
     def score(self, hw: HardwareProfile, engine: str = "fused",
-              shard: Optional[bool] = None, device=None) -> np.ndarray:
+              device=None) -> np.ndarray:
         """Per-design totals under ``hw`` via the selected engine.
-        ``shard``/``device`` pass through to
+        ``device`` passes through to
         :func:`repro.core.devicecost.score_frontier` (fused only)."""
         if engine == "fused":
             ids, sizes, weights, tiles = self._fused_arrays()
             return devicecost.score_frontier(
                 ids, sizes, weights, tiles,
-                self.n_segments, hw, shard=shard, device=device)
+                self.n_segments, hw, device=device)
         if engine != "grouped":
             raise ValueError(f"unknown engine: {engine!r}")
         segments = self.segments
@@ -609,52 +610,14 @@ class PackedSweep:
                             tuple(row[d] for row in per_point))
                 for d in range(n_parts)]
 
-    def _sharded_arrays(self, shard: Optional[bool]):
-        """The retained :func:`repro.core.devicecost.shard_sweep` product
-        for this sweep, or ``None`` when the flat path should serve it.
-
-        Built once per shard count and memoized on the frozen instance
-        (like ``_sweep_arrays``): repeat scores of a retained sweep are
-        pure pmap dispatches against device-committed shards — zero
-        host->device copies, zero recompiles across hardware swaps.
-        """
-        host_ids, _ = self._sweep_arrays()
-        n_dev = devicecost.sweep_shard_count(self.n_points, len(host_ids),
-                                             shard)
-        if (n_dev <= 1 and shard is not True) or self.n_points <= 1:
-            return None   # single-row sweeps: score_sweep's flat fallback
-        cache = self.__dict__.get("_f32_sh")
-        if cache is None:
-            cache = {}
-            object.__setattr__(self, "_f32_sh", cache)
-        state = cache.get(n_dev)
-        if state is None:
-            f0 = self.frontiers[0]
-            bucket = devicecost._pow2(len(f0.ids), 16)
-            if bucket > devicecost.sweep_chunk(-(-self.n_points // n_dev)):
-                state = False   # exceeds one fused chunk: chunked path
-            else:
-                state = devicecost.shard_sweep(*devicecost.pad_sweep(
-                    np.asarray(f0.ids, np.int32),
-                    np.stack([f.sizes for f in self.frontiers]),
-                    np.stack([f.weights for f in self.frontiers]),
-                    np.asarray(f0.tile_segments, np.int32), bucket),
-                    n_dev)
-            cache[n_dev] = state
-        return state or None
-
     def score(self, hw: HardwareProfile, engine: str = "fused",
-              shard: Optional[bool] = None, device=None) -> np.ndarray:
+              device=None) -> np.ndarray:
         """The ``[n_points, n_designs]`` totals grid under ``hw``.
 
         ``engine="grouped"`` scores each point's frontier through the
         PR-1 grouped oracle — bit-identical to looping ``cost_many(...,
-        engine="grouped")`` per workload.  ``shard`` splits the fused
-        grid across local devices along workload rows
-        (:func:`repro.core.devicecost.sweep_shard_count` decides; the
-        shard product is retained on the instance); ``device`` routes
-        the flat fused call onto one specific device and implies
-        ``shard=False``.
+        engine="grouped")`` per workload.  ``device`` routes the fused
+        call onto one specific device.
         """
         if self.n_designs == 0 or not self.points:
             return np.zeros((self.n_points, self.n_designs))
@@ -662,22 +625,14 @@ class PackedSweep:
             if self.rectangular:
                 host_ids, (ids, sizes, weights, tiles) = \
                     self._sweep_arrays()
-                if device is None and shard is not False:
-                    state = self._sharded_arrays(shard)
-                    if state is not None:
-                        return devicecost.score_sweep_sharded(
-                            state, self.n_designs, hw, host_ids)
-                    if shard is True and self.n_points == 1:
-                        # single-row grid: segment-range pmap fallback
-                        return self.frontiers[0].score(hw, shard=True)[None]
                 return devicecost.score_sweep(ids, sizes, weights, tiles,
                                               self.n_designs, hw,
                                               host_ids=host_ids,
-                                              shard=shard, device=device)
+                                              device=device)
             # non-rectangular: one spliced flat fused call over the
             # whole grid (point-major), not one dispatch per point
             flat = concat_frontiers(list(self.frontiers))
-            return flat.score(hw, shard=shard, device=device).reshape(
+            return flat.score(hw, device=device).reshape(
                 self.n_points, self.n_designs)
         if engine != "grouped":
             raise ValueError(f"unknown engine: {engine!r}")

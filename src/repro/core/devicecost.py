@@ -31,8 +31,9 @@ and segment counts pad to powers of two (chunked at ``_MAX_FUSED_RECORDS``)
 which makes a what-if-hardware question a pure parameter-table swap: a new
 profile builds new banks of identical shape and reuses the compiled
 executable with **zero recompilation** (asserted via :func:`trace_count`).
-Large frontiers shard across local devices with ``pmap`` over contiguous
-segment ranges.
+Several devices are the serving tier's concern:
+:class:`repro.serving.shards.ScoringShardPool` splits a product on design
+boundaries and routes each part here with ``device=``.
 
 Totals agree with the grouped PR-1 oracle to <=1e-6 relative (XLA fuses
 the banked computation differently than the per-kind eager predicts, and
@@ -44,14 +45,11 @@ from __future__ import annotations
 import dataclasses
 import functools
 import operator
-import os
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import memo, spans
 from repro.core.hardware import HardwareProfile
@@ -277,43 +275,16 @@ def invalidate_table(hw: HardwareProfile) -> None:
 
 # ---------------------------------------------------------------------------
 # Per-device bank placement.  A table's banks live wherever jax put them
-# (device 0); the sharded paths need them ON every participating device,
-# and the serving shard pool needs them committed to one SPECIFIC device.
-# Both placements happen once per (table, placement) and are interned in
-# the ``device_banks`` cache — after that, repeat scores touch the host
-# only for the O(R) availability check.  Keys carry ``id(table)``; the
-# value keeps a strong reference to the table, so the id cannot be reused
-# while its entry lives, and ``device_table`` discards a profile's
-# replicas the moment it swaps in a rebuilt table.
+# (device 0); the serving shard pool needs them committed to the one
+# SPECIFIC device each part runs on.  That placement happens once per
+# (table, device) and is interned in the ``device_banks`` cache — after
+# that, repeat scores touch the host only for the O(R) availability
+# check.  Keys carry ``id(table)``; the value keeps a strong reference to
+# the table, so the id cannot be reused while its entry lives, and
+# ``device_table`` discards a profile's replicas the moment it swaps in a
+# rebuilt table.
 # ---------------------------------------------------------------------------
 _BANK_REPLICAS = memo.DictCache(maxsize=32, name="device_banks")
-
-
-def _stack_on(devices, blocks: np.ndarray) -> jax.Array:
-    """``blocks`` ``[len(devices), ...]`` committed one leading-axis block
-    per device — the pmap input layout — via ``jax.device_put`` onto a
-    1-D mesh sharding."""
-    mesh = Mesh(np.asarray(devices), ("shard",))
-    return jax.device_put(blocks, NamedSharding(mesh, P("shard")))
-
-
-def _replicate_on(devices, x) -> jax.Array:
-    """One copy of ``x`` per device, stacked along a new leading axis."""
-    x = np.asarray(x)
-    return _stack_on(devices, np.broadcast_to(x, (len(devices),) + x.shape))
-
-
-def replicated_banks(table: DeviceTable, n_dev: int) -> Dict[str, jax.Array]:
-    """``table.banks`` stacked across the first ``n_dev`` local devices,
-    ready as a leading-axis pmap input."""
-    key = (id(table), n_dev)
-    hit = _BANK_REPLICAS.get(key)
-    if hit is not None and hit[0] is table:
-        return hit[1]
-    devices = jax.local_devices()[:n_dev]
-    stacked = {k: _replicate_on(devices, v) for k, v in table.banks.items()}
-    _BANK_REPLICAS.put(key, (table, stacked))
-    return stacked
 
 
 def _banks_on(table: DeviceTable, device) -> Dict[str, jax.Array]:
@@ -362,10 +333,10 @@ def bank_predict(banks: Dict[str, jax.Array], ids: jax.Array,
     neighbours) are written as explicit left-to-right adds over bank
     columns, never as a ``sum`` over a trailing axis: a TPU orders such
     a reduction by the array's layout, which follows the batch shape, so
-    a ``[2, R]`` pmap shard and a ``[8, R]`` flat chunk would round the
-    same record differently.  Written out, every record's value is the
-    same whatever the shape, which is what keeps sharded, chunked and
-    flat scores equal bit for bit.
+    a ``[2, R]`` chunk and an ``[8, R]`` chunk would round the same
+    record differently.  Written out, every record's value is the same
+    whatever the shape, which is what keeps pooled, chunked and flat
+    scores equal bit for bit.
     """
     def col(name: str, j: int) -> jax.Array:
         return banks[name][:, j][ids]
@@ -444,28 +415,6 @@ def _sweep_kernel(banks: Dict[str, jax.Array], ids: jax.Array,
 _sweep_jit = jax.jit(_sweep_kernel, static_argnums=(5, 6))
 
 
-@functools.lru_cache(maxsize=64)
-def _score_pmap(n_segments: int, with_knn: bool):
-    # banks arrive pre-stacked via replicated_banks (one replica per
-    # device, placed once) — in_axes=0 consumes them without the per-call
-    # host broadcast that in_axes=None would re-issue
-    return jax.pmap(
-        functools.partial(_score_kernel, n_segments=n_segments,
-                          with_knn=with_knn),
-        in_axes=(0, 0, 0, 0, 0))
-
-
-@functools.lru_cache(maxsize=64)
-def _sweep_pmap(n_segments: int, with_knn: bool):
-    """Workload-row twin of :func:`_score_pmap`: every device scores its
-    own ``[W_shard, R]`` slice of the sweep with the shared record
-    layout (ids/tile_segments replicated, sizes/weights sharded)."""
-    return jax.pmap(
-        functools.partial(_sweep_kernel, n_segments=n_segments,
-                          with_knn=with_knn),
-        in_axes=(0, 0, 0, 0, 0))
-
-
 def _pad_records(ids: np.ndarray, sizes: np.ndarray, weights: np.ndarray,
                  tile_segments: np.ndarray, bucket: int
                  ) -> Tuple[np.ndarray, ...]:
@@ -498,115 +447,6 @@ def _pad_records(ids: np.ndarray, sizes: np.ndarray, weights: np.ndarray,
                            ).astype(np.int32))
 
 
-# ---------------------------------------------------------------------------
-# Auto-shard threshold.  pmap dispatch costs more than jit dispatch, so
-# small products must stay on one device and large ones must not miss the
-# sharded path.  The cut-over is a per-process knob resolved as: explicit
-# ``set_shard_threshold`` override > ``REPRO_SHARD_THRESHOLD`` env var >
-# a lazily-run device-count-aware calibration (below).
-# ---------------------------------------------------------------------------
-_SHARD_STATE: Dict[str, Optional[int]] = {"override": None,
-                                          "calibrated": None}
-
-#: pow2 record buckets the calibration probes, smallest first
-_CALIBRATION_BUCKETS = (1024, 4096)
-
-SHARD_THRESHOLD_ENV = "REPRO_SHARD_THRESHOLD"
-
-
-def set_shard_threshold(records: Optional[int]) -> None:
-    """Override the auto-shard cut-over (records for frontiers, cells for
-    sweeps).  ``None`` drops the override back to the env-var/calibrated
-    default; the calibration result itself stays memoized."""
-    with MEMO_LOCK:
-        _SHARD_STATE["override"] = \
-            None if records is None else max(int(records), 1)
-
-
-def shard_threshold() -> int:
-    """Product size (frontier records / sweep cells) at which the auto
-    path starts sharding across devices.  See :func:`set_shard_threshold`
-    and the ``REPRO_SHARD_THRESHOLD`` env var; with neither set, a quick
-    calibration times jit vs pmap dispatch at :data:`_CALIBRATION_BUCKETS`
-    once per process (single-device processes skip straight to "never")."""
-    override = _SHARD_STATE["override"]
-    if override is not None:
-        return override
-    env = os.environ.get(SHARD_THRESHOLD_ENV)
-    if env:
-        try:
-            return max(int(env), 1)
-        except ValueError:
-            pass
-    calibrated = _SHARD_STATE["calibrated"]
-    if calibrated is None:
-        # racing threads calibrate redundantly but agree; not worth
-        # holding the memo lock across timed device dispatches
-        # lint: unlocked(idempotent single-key write; races agree on value)
-        calibrated = _SHARD_STATE["calibrated"] = _calibrate_shard_threshold()
-    return calibrated
-
-
-def _calibration_table() -> DeviceTable:
-    """A tiny synthetic all-linear table (row 0 scores y = x) so the
-    calibration never touches a real profile's banks or model interning."""
-    m = 16
-    lin_w = np.zeros((m, 4), np.float32)
-    lin_w[:, 0] = 1.0
-    banks = {k: jnp.asarray(v) for k, v in {
-        "kinds": np.zeros(m, np.int32), "lin_w": lin_w,
-        "lin_y0": np.zeros(m, np.float32),
-        "sig_c": np.zeros((m, _SIG_SLOTS), np.float32),
-        "sig_k": np.ones((m, _SIG_SLOTS), np.float32),
-        "sig_x0": np.zeros((m, _SIG_SLOTS), np.float32),
-        "sig_y0": np.zeros(m, np.float32),
-        "knn_lx": np.full((m, _KNN_SLOTS), KNN_SENTINEL, np.float32),
-        "knn_y": np.zeros((m, _KNN_SLOTS), np.float32),
-        "xlo": np.ones(m, np.float32),
-        "xhi": np.full(m, 1e9, np.float32)}.items()}
-    return DeviceTable("__shard_calibration__", banks, np.ones(m, bool),
-                       m, _SIG_SLOTS, _KNN_SLOTS, has_knn=False,
-                       models_ref=-1)
-
-
-def _best_of(fn, reps: int = 3) -> float:
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        fn()
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-
-def _calibrate_shard_threshold() -> int:
-    """Smallest probed record bucket where the pmap path beats the jit
-    path on synthetic frontiers (TILE-sized designs, shared shapes with
-    real traffic); 4x the largest bucket when pmap never wins, and
-    effectively "never" on a single-device process."""
-    if len(jax.local_devices()) <= 1:
-        return _MAX_FUSED_RECORDS
-    table = _calibration_table()
-    for bucket in _CALIBRATION_BUCKETS:
-        ids = np.zeros(bucket, np.int32)
-        sizes = np.ones(bucket, np.float32)
-        weights = np.ones(bucket, np.float32)
-        tiles = np.arange(bucket // TILE, dtype=np.int64)
-        n_seg = bucket // TILE
-
-        def _single():
-            np.asarray(_score_jit(table.banks, ids, sizes, weights,
-                                  tiles.astype(np.int32),
-                                  _pow2(n_seg, 16), False))
-
-        def _sharded():
-            _score_sharded(table, ids, sizes, weights, tiles, n_seg)
-
-        _single(), _sharded()          # compile both paths first
-        if _best_of(_sharded) <= _best_of(_single):
-            return bucket
-    return 4 * _CALIBRATION_BUCKETS[-1]
-
-
 def _dispatch(records: int, points: int, device=None) -> spans.span:
     """The span around padding, placing and issuing one fused call
     (``device`` -1: the default device)."""
@@ -632,7 +472,7 @@ def _chunk_cuts(tile_segments: np.ndarray, chunk_tiles: int) -> List[int]:
     """Tile offsets that cut a record layout into fused chunks of at most
     ``chunk_tiles`` tiles, each cut on a design boundary: every design's
     records then reduce in one ``segment_sum``, so a chunked score equals
-    a one-call (or sharded) score bit for bit.  Only a design longer than
+    a one-call (or pooled) score bit for bit.  Only a design longer than
     a whole chunk is cut inside."""
     n = len(tile_segments)
     cuts = [0]
@@ -643,37 +483,37 @@ def _chunk_cuts(tile_segments: np.ndarray, chunk_tiles: int) -> List[int]:
     return cuts + [n]
 
 
+def _refuse_shard(shard: bool) -> None:
+    """The scorers take ``shard`` only as ``False``: the benchmark's
+    warm-up (``bench/system.py``) still passes it, and the keyword goes
+    once that file stops.  Several devices score one product through
+    :class:`repro.serving.shards.ScoringShardPool`, never here."""
+    if shard:
+        raise ValueError("shard=True is not supported: score a product "
+                         "across devices with "
+                         "repro.serving.shards.ScoringShardPool")
+
+
 def score_frontier(ids: np.ndarray, sizes: np.ndarray, weights: np.ndarray,
                    tile_segments: np.ndarray, n_segments: int,
-                   hw: HardwareProfile,
-                   shard: Optional[bool] = None,
+                   hw: HardwareProfile, shard: bool = False,
                    device=None) -> np.ndarray:
     """Per-design totals for packed frontier records, in one fused call.
 
     Records must be TILE-aligned per design and ``tile_segments`` sorted
     ascending — exactly the layout
-    :func:`repro.core.batchcost.pack_frontier` emits.  ``shard=None``
-    auto-shards across local devices when more than one is present and
-    the frontier clears :func:`shard_threshold` records; ``shard=True``
-    forces the pmap path (works on a single device too), ``shard=False``
-    forces the single-device jit path.  ``device`` routes the jit path
-    onto one specific local device (banks committed there once, see
-    :func:`_banks_on`) — the serving shard pool's dispatch primitive;
-    it implies ``shard=False``.
+    :func:`repro.core.batchcost.pack_frontier` emits.  ``device`` routes
+    the call onto one specific local device (banks committed there once,
+    see :func:`_banks_on`) — the serving shard pool's dispatch
+    primitive; ``None`` scores on the default device.  ``shard``: see
+    :func:`_refuse_shard`.
     """
+    _refuse_shard(shard)
     if n_segments == 0:
         return np.zeros(0, np.float64)
     table = device_table(hw)
     _check_frontier(table, ids)
     n_pad = _pow2(n_segments, 16)
-    if shard is None:
-        shard = device is None and len(jax.local_devices()) > 1 \
-            and len(ids) >= shard_threshold()
-    if shard:
-        return faults.corrupt(
-            "devicecost.fused",
-            _score_sharded(table, ids, sizes, weights, tile_segments,
-                           n_segments))
     banks = table.banks if device is None else _banks_on(table, device)
     totals = np.zeros(n_pad, np.float64)
     cuts = _chunk_cuts(tile_segments, _MAX_FUSED_RECORDS // TILE)
@@ -745,91 +585,10 @@ def to_device_sweep(ids, sizes, weights, tile_segments) -> Tuple:
                      for a in (ids, sizes, weights, tile_segments))
 
 
-def sweep_shard_count(w_axis: int, n_records: int,
-                      shard: Optional[bool] = None) -> int:
-    """How many workload-row shards a ``[w_axis, n_records]`` sweep
-    should use (1 means the flat single-device path).
-
-    ``shard=None`` auto-shards when more than one local device is
-    present, the sweep has rows to split, and the grid clears
-    :func:`shard_threshold` cells; ``shard=True`` forces
-    ``min(devices, w_axis)`` shards (>= 1, so the pmap path is exercised
-    even on one device); ``shard=False`` forces 1."""
-    if shard is False or w_axis <= 0:
-        return 1
-    n_dev = max(min(len(jax.local_devices()), w_axis), 1)
-    if shard is True:
-        return n_dev
-    if n_dev < 2:
-        return 1
-    return n_dev if w_axis * max(n_records, 1) >= shard_threshold() else 1
-
-
-def shard_sweep(ids: np.ndarray, sizes: np.ndarray, weights: np.ndarray,
-                tile_segments: np.ndarray, n_dev: int) -> Tuple:
-    """Stack record-padded rectangular sweep arrays into per-device
-    workload-row shards committed to the first ``n_dev`` local devices.
-
-    ``sizes``/``weights`` are host ``[W, R]`` (R already at its pow2
-    bucket, e.g. via :func:`pad_sweep`).  A ragged W pads by repeating
-    the last sizes row with all-zero weights; the caller slices the
-    output back to ``[:W]``, so pad rows are computed-and-dropped, never
-    observable — the sharded grid stays bit-identical to the flat call.
-    Returns ``(w_axis, (ids, sizes, weights, tile_segments))`` where
-    ``sizes``/``weights`` are pmap-sharded (one row block per device)
-    and ``ids``/``tile_segments`` replicated: a retained sweep keeps the
-    tuple and every repeat score is a pure pmap dispatch with zero
-    host->device copies."""
-    devices = jax.local_devices()[:n_dev]
-    w_axis = int(sizes.shape[0])
-    w_shard = -(-w_axis // n_dev)
-    pad = n_dev * w_shard - w_axis
-    sizes = np.asarray(sizes, np.float32)
-    weights = np.asarray(weights, np.float32)
-    if pad:
-        sizes = np.concatenate([sizes, np.repeat(sizes[-1:], pad, axis=0)])
-        weights = np.concatenate(
-            [weights, np.zeros((pad, weights.shape[1]), np.float32)])
-    return w_axis, (
-        _replicate_on(devices, np.asarray(ids, np.int32)),
-        _stack_on(devices, sizes.reshape(n_dev, w_shard, -1)),
-        _stack_on(devices, weights.reshape(n_dev, w_shard, -1)),
-        _replicate_on(devices, np.asarray(tile_segments, np.int32)))
-
-
-def _sweep_sharded(table: DeviceTable, state: Tuple,
-                   n_segments: int) -> np.ndarray:
-    """Dispatch a :func:`shard_sweep` product: one pmap call, per-device
-    bank replicas, output rows re-flattened and pad rows sliced off."""
-    w_axis, (ids_sh, sizes_sh, weights_sh, tiles_sh) = state
-    n_dev = int(sizes_sh.shape[0])
-    records = int(ids_sh.shape[-1])
-    with spans.span(spans.DISPATCH, records=records, points=w_axis,
-                    devices=n_dev):
-        out = _sweep_pmap(_pow2(n_segments, 16), table.has_knn)(
-            replicated_banks(table, n_dev), ids_sh, sizes_sh, weights_sh,
-            tiles_sh)
-    out = _fetch(out, records)
-    return out.reshape(-1, out.shape[-1])[:w_axis, :n_segments]
-
-
-def score_sweep_sharded(state: Tuple, n_segments: int, hw: HardwareProfile,
-                        host_ids: np.ndarray) -> np.ndarray:
-    """Steady-path twin of :func:`score_sweep` for a prebuilt (retained)
-    :func:`shard_sweep` product: beyond the O(R) availability check this
-    is one pmap dispatch against device-committed shards — zero copies,
-    and hardware swaps reuse the compiled executable."""
-    table = device_table(hw)
-    _check_frontier(table, host_ids)
-    return faults.corrupt("devicecost.fused",
-                          _sweep_sharded(table, state, n_segments))
-
-
 def score_sweep(ids, sizes, weights, tile_segments, n_segments: int,
                 hw: HardwareProfile,
                 host_ids: Optional[np.ndarray] = None,
-                shard: Optional[bool] = None,
-                device=None) -> np.ndarray:
+                shard: bool = False, device=None) -> np.ndarray:
     """Per-(workload, design) totals for a rectangular sweep, one fused
     call.
 
@@ -842,17 +601,11 @@ def score_sweep(ids, sizes, weights, tile_segments, n_segments: int,
     the array back from the device.  Returns ``[W, n_segments]``.
     Shapes are pow2-bucketed like :func:`score_frontier`, so repeat
     sweeps (and what-if-hardware swaps against a sweep) reuse the
-    compiled executable with zero recompilation.
-
-    ``shard`` splits the grid across local devices along workload rows
-    (:func:`sweep_shard_count` decides the shard count; single-row
-    sweeps fall back to PR 2's segment-range pmap) — ``None``
-    auto-shards past :func:`shard_threshold` cells, ``True`` forces the
-    sharded path, ``False`` pins the flat path.  Retained sweeps should
-    prefer :func:`score_sweep_sharded`, which skips the per-call shard
-    build.  ``device`` routes the flat call onto one specific device
-    (implies ``shard=False``).
+    compiled executable with zero recompilation.  ``device`` routes the
+    call onto one specific local device, as in :func:`score_frontier`;
+    ``shard``: see :func:`_refuse_shard`.
     """
+    _refuse_shard(shard)
     w_axis = int(sizes.shape[0])
     if n_segments == 0 or w_axis == 0:
         return np.zeros((w_axis, n_segments), np.float64)
@@ -862,30 +615,6 @@ def score_sweep(ids, sizes, weights, tile_segments, n_segments: int,
     n_pad = _pow2(n_segments, 16)
     chunk_r = sweep_chunk(w_axis)
     n = len(host_ids)
-    if device is None and shard is not False \
-            and isinstance(sizes, np.ndarray):
-        # device-resident retained arrays skip this block: re-sharding
-        # them would pull every array back to the host per call — a
-        # retained sweep shards once via score_sweep_sharded instead
-        n_dev = sweep_shard_count(w_axis, n, shard)
-        if (n_dev > 1 or (shard is True and w_axis > 1)) and \
-                _pow2(n, 16) <= sweep_chunk(-(-w_axis // n_dev)):
-            with spans.span(spans.DISPATCH, records=_pow2(n, 16),
-                            points=w_axis, devices=n_dev):
-                padded = pad_sweep(host_ids, np.asarray(sizes),
-                                   np.asarray(weights),
-                                   np.asarray(tile_segments), _pow2(n, 16))
-                state = shard_sweep(*padded, n_dev)
-            return faults.corrupt("devicecost.fused",
-                                  _sweep_sharded(table, state, n_segments))
-        if w_axis == 1 and (shard is True or (
-                shard is None and len(jax.local_devices()) > 1
-                and n >= shard_threshold())):
-            # flat frontier disguised as a 1-row sweep: segment-range pmap
-            flat = _score_sharded(table, host_ids, np.asarray(sizes)[0],
-                                  np.asarray(weights)[0],
-                                  np.asarray(tile_segments), n_segments)
-            return faults.corrupt("devicecost.fused", flat[None])
     banks = table.banks if device is None else _banks_on(table, device)
     if n == _pow2(n, 16) and n <= chunk_r:
         # bucket-aligned single chunk — the steady path: PackedSweep
@@ -918,31 +647,3 @@ def score_sweep(ids, sizes, weights, tile_segments, n_segments: int,
             out = _sweep_jit(banks, *padded, n_pad, table.has_knn)
         totals += _fetch(out, bucket)
     return faults.corrupt("devicecost.fused", totals[:, :n_segments])
-
-
-def _score_sharded(table: DeviceTable, ids: np.ndarray, sizes: np.ndarray,
-                   weights: np.ndarray, tile_segments: np.ndarray,
-                   n_segments: int) -> np.ndarray:
-    """pmap the scorer over contiguous segment ranges, one per device."""
-    from repro.core.templatecost import segment_ranges  # circular at top
-    devices = jax.local_devices()
-    n_dev = max(min(len(devices), n_segments), 1)
-    seg_cuts, tile_cuts = segment_ranges(tile_segments, n_segments, n_dev)
-    rec_bucket = _pow2(int(max(np.diff(tile_cuts), default=1)) * TILE, 16)
-    seg_pad = _pow2(int(max(np.diff(seg_cuts), default=1)), 16)
-    with spans.span(spans.DISPATCH, records=rec_bucket, points=1,
-                    devices=n_dev):
-        shards = []
-        for d in range(n_dev):
-            t0, t1 = tile_cuts[d], tile_cuts[d + 1]
-            r0, r1 = t0 * TILE, t1 * TILE
-            shards.append(_pad_records(ids[r0:r1], sizes[r0:r1],
-                                       weights[r0:r1],
-                                       tile_segments[t0:t1] - seg_cuts[d],
-                                       rec_bucket))
-        stacked = [np.stack([s[i] for s in shards]) for i in range(4)]
-        out = _score_pmap(seg_pad, table.has_knn)(
-            replicated_banks(table, n_dev), *stacked)
-    out = _fetch(out, rec_bucket)
-    return np.concatenate([
-        out[d, :seg_cuts[d + 1] - seg_cuts[d]] for d in range(n_dev)])

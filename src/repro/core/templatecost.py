@@ -397,9 +397,8 @@ def segment_ranges(tile_segments: np.ndarray, n_segments: int,
     ``tile_cuts[d]:tile_cuts[d+1]`` — records ``* TILE``.  Design blocks
     are tile-aligned by construction, so tile cuts never split a design;
     every segment's records land wholly in one shard, which is what
-    keeps sharded totals bit-identical to the flat reduction.  Shared by
-    ``devicecost._score_sharded`` (pmap shards), ``PackedFrontier.split``
-    (the serving shard pool's partitions) and per-shard packing."""
+    keeps pooled totals bit-identical to the flat reduction.  Used by
+    ``PackedFrontier.split`` (the serving shard pool's partitions)."""
     seg_cuts = np.asarray([round(n_segments * d / n_parts)
                            for d in range(n_parts + 1)])
     tile_cuts = np.searchsorted(tile_segments, seg_cuts, side="left")

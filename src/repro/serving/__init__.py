@@ -21,7 +21,7 @@ bit-identical totals before any future resolves.
 
 Self-healing (PR 8): failed/timed-out shard parts retry on a different
 device behind a per-device circuit breaker; non-finite fused results
-fall back fused-sharded -> fused-flat -> grouped oracle per evaluation
+fall back pooled -> fused-flat -> grouped oracle per evaluation
 (answers carry the producing ``engine`` tag); a supervisor resurrects a
 crashed worker, failing in-flight futures with the typed
 :class:`~repro.serving.admission.WorkerCrashed`.  Fault-tolerance state

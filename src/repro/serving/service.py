@@ -1056,7 +1056,7 @@ class DesignCalculatorService:
                      deadline: Optional[float]
                      ) -> Optional[Tuple[int, int]]:
         """Score one (profile, axis) group through the degraded-engine
-        fallback chain: fused-sharded -> fused-flat -> grouped oracle.
+        fallback chain: pooled -> fused-flat -> grouped oracle.
 
         Fused results are validated with a cheap ``isfinite`` reduction
         (NaN-poisoned parameter banks produce *finite-looking shapes*
@@ -1138,17 +1138,17 @@ class DesignCalculatorService:
                 if isinstance(exc, NonFiniteScore):
                     with self._lock:
                         self._stats.nonfinite_groups += 1
-                _LOG.warning("fused sharded scoring failed for %r (%r); "
+                _LOG.warning("pooled fused scoring failed for %r (%r); "
                              "retrying flat", hw.name, exc)
             if not probe(0):
                 return None
             try:
-                flat = product.score(hw, engine="fused", shard=False)
+                flat = product.score(hw, engine="fused")
                 if not np.isfinite(np.asarray(flat)).all():
                     raise NonFiniteScore(
                         f"flat fused totals for {hw.name!r} contain "
                         f"non-finite values")
-                # flat success means the banks are fine: the sharded
+                # flat success means the banks are fine: the pooled
                 # failure was device/shard trouble (the pool's breaker
                 # handles that) — engine health resets, no demotion
                 self._note_fused_ok(hw.name)
@@ -1255,7 +1255,7 @@ class DesignCalculatorService:
             def _probe(shard_idx: int, _evals=evals) -> bool:
                 """Deadline check between shard dispatches (PR 6's
                 between-scoring-calls contract, extended inside one
-                sharded call); False once no owner is left alive."""
+                pooled call); False once no owner is left alive."""
                 now = time.monotonic()
                 alive = False
                 for ev in _evals:

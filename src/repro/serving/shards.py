@@ -294,8 +294,7 @@ class ScoringShardPool:
 
         def _run():
             faults.check("shards.dispatch", device.id)
-            return part.score(hw, engine=engine, shard=False,
-                              device=device)
+            return part.score(hw, engine=engine, device=device)
         return self._executor().submit(_run)
 
     def _await(self, future, deadline: Optional[float]):
@@ -416,7 +415,7 @@ class ScoringShardPool:
         # last resort: rescore ONLY this part, flat, in the worker thread
         self._count("shard_rescored")
         try:
-            value = part.score(hw, engine=engine, shard=False)
+            value = part.score(hw, engine=engine)
         except Exception:
             if last_error is not None:
                 raise last_error

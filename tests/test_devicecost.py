@@ -1,5 +1,5 @@
 """Fused device-resident frontier scoring (PR 2 tentpole): parameter-table
-swaps without recompilation, sharded scoring, bank coverage of every model
+swaps without recompilation, pooled scoring, bank coverage of every model
 kind, and the bounded compiled-shape set."""
 import numpy as np
 import pytest
@@ -50,12 +50,22 @@ def test_bucketing_bounds_compiled_shapes(hw_analytical):
     assert devicecost.trace_count() == before
 
 
-def test_sharded_path_matches_single_device(hw_analytical):
+@pytest.mark.devices(2)
+def test_pool_parts_match_single_device(hw_analytical, device_count):
+    """A 200-design frontier split across two devices by the scoring
+    shard pool equals the flat one-device call."""
+    from repro.serving import ScoringShardPool
+    assert device_count == 2
     specs, w, mix = _frontier()
     packed = pack_frontier(specs * 40, w, mix)   # 200 designs
-    single = packed.score(hw_analytical, shard=False)
-    sharded = packed.score(hw_analytical, shard=True)
-    np.testing.assert_allclose(sharded, single, rtol=1e-12)
+    single = packed.score(hw_analytical)
+    pool = ScoringShardPool(min_cells_per_shard=1)
+    try:
+        pooled, used = pool.score_frontier(packed, hw_analytical)
+    finally:
+        pool.close()
+    assert used == 2
+    np.testing.assert_allclose(pooled, single, rtol=1e-12)
 
 
 def test_chunked_scoring_matches_unchunked(hw_analytical, monkeypatch):
@@ -71,15 +81,15 @@ def test_chunked_scoring_matches_unchunked(hw_analytical, monkeypatch):
 def test_chunked_sweep_matches_one_call_bitwise(hw_analytical, monkeypatch):
     """A sweep too large for one fused chunk scores chunk by chunk; cut on
     design boundaries, the grid equals the one-call grid bit for bit (the
-    equality the sharded path's parity with the flat path rests on)."""
+    equality the shard pool's parity with the flat path rests on)."""
     specs, w, mix = _frontier()
     workloads = [w, Workload(n_entries=500_000, zipf_alpha=1.0)]
     full = batchcost.pack_sweep(specs * 40, workloads, mix).score(
-        hw_analytical, shard=False)
+        hw_analytical)
     monkeypatch.setattr(devicecost, "_MAX_FUSED_RECORDS", 512)
     batchcost.clear_caches()
     chunked = batchcost.pack_sweep(specs * 40, workloads, mix).score(
-        hw_analytical, shard=False)
+        hw_analytical)
     np.testing.assert_array_equal(chunked, full)
 
 
@@ -109,8 +119,8 @@ def test_knn_models_join_the_device_table(hw_analytical, n_points):
 @pytest.mark.parametrize("with_knn", [False, True], ids=["plain", "knn"])
 def test_bank_predict_sums_in_a_fixed_order(hw_analytical, with_knn):
     """Per-record values must not depend on the batch shape: a TPU orders
-    a ``reduce_sum`` by layout, so a [2, R] pmap shard and an [8, R]
-    flat chunk rounded the same record differently.  bank_predict spells
+    a ``reduce_sum`` by layout, so a [2, R] chunk and an [8, R] chunk
+    rounded the same record differently.  bank_predict spells
     its short sums out as ordered adds — no reduction primitive at all."""
     import jax
     import jax.numpy as jnp

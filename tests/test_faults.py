@@ -96,7 +96,7 @@ def test_failed_part_retries_on_a_different_device(device_count):
     hw = hw1()
     packed = _packed()
     dev0 = pool.devices[0].id
-    baseline = packed.score(hw, engine="fused", shard=False)
+    baseline = packed.score(hw, engine="fused")
     plan = FaultPlan(3, [FaultRule("shards.dispatch", kind="error",
                                    key=dev0, at=(0,))])
     try:
@@ -115,7 +115,7 @@ def test_quarantine_opens_and_half_open_probe_recovers():
                             part_timeout_s=5.0)
     hw = hw1()
     packed = _packed()
-    baseline = packed.score(hw, engine="fused", shard=False)
+    baseline = packed.score(hw, engine="fused")
     # exactly two dispatch failures: initial + same-device retry -> the
     # breaker opens; the flat rescore still answers the window
     plan = FaultPlan(5, [FaultRule("shards.dispatch", kind="error",
@@ -150,7 +150,7 @@ def test_hung_part_times_out_and_is_abandoned():
     pool = ScoringShardPool(1, part_timeout_s=0.05)
     hw = hw1()
     packed = _packed()
-    baseline = packed.score(hw, engine="fused", shard=False)
+    baseline = packed.score(hw, engine="fused")
     # warm the device-routed jit through the executor path (a rule-free
     # plan forces it) so the timing below measures healing, not compiles
     with FaultPlan(0, []).activate():

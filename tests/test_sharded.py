@@ -1,11 +1,13 @@
-"""Multi-device sharded scoring + device-routed serving (PR 7).
+"""Multi-device scoring through the serving shard pool (PR 7).
 
-The contract: sharded ``score_frontier``/``score_sweep`` are
-bit-identical to the single-device jit path (padding/masking only ever
-adds rows that are computed-and-dropped), argmins are identical through
-``design_beam``/``whatif.workload_sweep``, repeat sharded scores and
-hardware swaps recompile nothing, and the serving shard pool partitions
-a window across >= 2 devices while keeping the PR 6 deadline semantics.
+The scoring-shard pool is the one way several devices score a product.
+The contract: its merged frontier totals and sweep grids are
+bit-identical to the flat one-device ``score`` call (parts cut on
+design boundaries), answers through ``whatif.workload_sweep`` and
+``complete_design`` are identical run directly or through a service
+with one scoring shard per device, repeat pool scores and hardware
+swaps recompile nothing, and the pool partitions a window across >= 2
+devices while keeping the PR 6 deadline semantics.
 
 Multi-device cases carry ``@pytest.mark.devices(n)``: the
 ``device_count`` fixture re-invokes them in a subprocess under
@@ -18,7 +20,7 @@ import numpy as np
 import pytest
 
 from repro.core import batchcost, devicecost, elements as el, whatif
-from repro.core.autocomplete import design_beam
+from repro.core.autocomplete import complete_design
 from repro.core.batchcost import pack_frontier, pack_sweep
 from repro.core.hardware import hw1, hw3
 from repro.core.synthesis import Workload
@@ -42,37 +44,11 @@ def _workloads(n=5):
             for i in range(n)]
 
 
-@pytest.fixture(autouse=True)
-def _reset_threshold():
-    yield
-    devicecost.set_shard_threshold(None)
-
-
-# ---------------------------------------------------------------------------
-# Satellite 1: the shard threshold knob
-# ---------------------------------------------------------------------------
-def test_shard_threshold_override_wins():
-    devicecost.set_shard_threshold(123)
-    assert devicecost.shard_threshold() == 123
-    devicecost.set_shard_threshold(None)
-    assert devicecost.shard_threshold() != 123
-
-
-def test_shard_threshold_env_var(monkeypatch):
-    monkeypatch.setenv(devicecost.SHARD_THRESHOLD_ENV, "777")
-    assert devicecost.shard_threshold() == 777
-    devicecost.set_shard_threshold(55)   # explicit override beats env
-    assert devicecost.shard_threshold() == 55
-    monkeypatch.setenv(devicecost.SHARD_THRESHOLD_ENV, "not-a-number")
-    devicecost.set_shard_threshold(None)
-    assert devicecost.shard_threshold() >= 1   # bad env falls through
-
-
-def test_single_device_calibration_never_shards(device_count):
-    if device_count > 1:
-        pytest.skip("calibration default is device-count dependent")
-    assert devicecost._calibrate_shard_threshold() \
-        == devicecost._MAX_FUSED_RECORDS
+def _ragged_workloads():
+    """Points whose data sizes change the designs' level structure: a
+    non-rectangular sweep (one spliced flat call, no shared ids)."""
+    return [BASE, dataclasses.replace(BASE, n_entries=50_000_000),
+            dataclasses.replace(BASE, n_entries=1000)]
 
 
 def test_forced_device_env_helpers():
@@ -89,38 +65,80 @@ def test_forced_device_env_helpers():
 # ---------------------------------------------------------------------------
 def test_frontier_split_merge_bit_identical(hw_analytical):
     packed = pack_frontier(_specs(), BASE, MIX)
-    whole = packed.score(hw_analytical, shard=False)
+    whole = packed.score(hw_analytical)
     for n_parts in (1, 2, 3, len(_specs()), 64):
         parts = packed.split(n_parts)
         assert sum(p.n_segments for p in parts) == packed.n_segments
         merged = np.concatenate(
-            [p.score(hw_analytical, shard=False) for p in parts])
+            [p.score(hw_analytical) for p in parts])
         assert np.array_equal(merged, whole)
 
 
 def test_sweep_split_merge_bit_identical(hw_analytical):
     sweep = pack_sweep(_specs(), _workloads(), [MIX] * 5)
-    whole = sweep.score(hw_analytical, shard=False)
+    whole = sweep.score(hw_analytical)
     for n_parts in (2, 3, 64):
         parts = sweep.split(n_parts)
         assert all(p.rectangular for p in parts)   # ids stay shared
         merged = np.concatenate(
-            [p.score(hw_analytical, shard=False) for p in parts], axis=1)
+            [p.score(hw_analytical) for p in parts], axis=1)
         assert np.array_equal(merged, whole)
 
 
-def test_sharded_paths_bit_identical_here(hw_analytical):
-    """shard=True (pmap, whatever the local device count) must match the
-    flat jit path bit for bit — the 1-device leg of the parity matrix."""
-    packed = pack_frontier(_specs(), BASE, MIX)
-    assert np.array_equal(packed.score(hw_analytical, shard=True),
-                          packed.score(hw_analytical, shard=False))
-    sweep = pack_sweep(_specs(), _workloads(), [MIX] * 5)
-    assert np.array_equal(sweep.score(hw_analytical, shard=True),
-                          sweep.score(hw_analytical, shard=False))
-    one_row = pack_sweep(_specs(), [BASE], [MIX])
-    assert np.array_equal(one_row.score(hw_analytical, shard=True),
-                          one_row.score(hw_analytical, shard=False))
+@pytest.mark.parametrize("kind", ["frontier", "sweep", "one_row",
+                                  "ragged"])
+def test_pool_matches_flat_here(hw_analytical, kind):
+    """On this process's devices, one scoring shard and a service answer
+    equal the flat ``score`` call bit for bit — the 1-device leg of the
+    parity matrix, the non-rectangular sweep's spliced call included."""
+    specs = _specs()
+    if kind == "frontier":
+        product, call = pack_frontier(specs, BASE, MIX), "score_frontier"
+    else:
+        workloads = {"sweep": _workloads(), "one_row": [BASE],
+                     "ragged": _ragged_workloads()}[kind]
+        product = pack_sweep(specs, workloads, [MIX] * len(workloads))
+        call = "score_sweep"
+        assert product.rectangular == (kind != "ragged")
+    flat = product.score(hw_analytical)
+    pool = ScoringShardPool(1)
+    try:
+        pooled, used = getattr(pool, call)(product, hw_analytical)
+    finally:
+        pool.close()
+    assert used == 1
+    assert np.array_equal(pooled, flat)
+    with DesignCalculatorService([hw_analytical], scoring_shards=1) as svc:
+        if kind == "frontier":
+            answer = svc.what_if_design(specs[0], specs[1], BASE,
+                                        hw_analytical, MIX)
+            served = [answer.baseline_seconds, answer.variant_seconds]
+            flat = pack_frontier(specs[:2], BASE, MIX).score(hw_analytical)
+        else:
+            served = svc.workload_sweep(specs, workloads, hw_analytical,
+                                        [MIX] * len(workloads)).totals
+        assert svc.stats()["shard_dispatches"] == 0
+    assert np.array_equal(np.asarray(served), flat)
+
+
+@pytest.mark.parametrize("kind", ["frontier", "sweep"])
+def test_score_entry_points_refuse_shard_true(hw_analytical, kind):
+    """The fused entry points keep ``shard`` only as ``False`` (the flat
+    call); ``shard=True`` is refused and names the shard pool."""
+    if kind == "frontier":
+        product = pack_frontier(_specs(), BASE, MIX)
+        entry, n = devicecost.score_frontier, product.n_segments
+        args, kwargs = product._fused_arrays(), {}
+    else:
+        product = pack_sweep(_specs(), _workloads(), [MIX] * 5)
+        entry, n = devicecost.score_sweep, product.n_designs
+        host_ids, args = product._sweep_arrays()
+        kwargs = {"host_ids": host_ids}
+    with pytest.raises(ValueError, match="ScoringShardPool"):
+        entry(*args, n, hw_analytical, shard=True, **kwargs)
+    assert np.array_equal(
+        entry(*args, n, hw_analytical, shard=False, **kwargs),
+        product.score(hw_analytical))
 
 
 def test_pool_degenerate_is_plain_score(hw_analytical):
@@ -134,6 +152,20 @@ def test_pool_degenerate_is_plain_score(hw_analytical):
     grid, used = pool.score_sweep(sweep, hw_analytical)
     assert used == 1
     assert np.array_equal(grid, sweep.score(hw_analytical))
+
+
+def test_one_shard_pool_places_no_banks_here(hw_analytical):
+    """One shard, with no deadline and no fault plan, scores in the
+    calling thread on the default device: no banks are committed to a
+    device for it."""
+    placed = set(devicecost._BANK_REPLICAS.keys())
+    pool = ScoringShardPool(1)
+    try:
+        pool.score_sweep(pack_sweep(_specs(), _workloads(), [MIX] * 5),
+                         hw_analytical)
+    finally:
+        pool.close()
+    assert set(devicecost._BANK_REPLICAS.keys()) == placed
 
 
 def test_pool_abort_when_probe_reports_dead(hw_analytical):
@@ -156,72 +188,76 @@ def test_sharded_parity_under_devices(n_devices, device_count):
     assert device_count == n_devices
     hw = hw1()
     specs, workloads = _specs(), _workloads()
-    devicecost.set_shard_threshold(1)   # every auto decision shards
-
-    # frontier: sharded bit-identical to flat, and the auto path shards
+    mixes = [MIX] * len(workloads)
     packed = pack_frontier(specs, BASE, MIX)
-    flat = packed.score(hw, shard=False)
-    assert np.array_equal(packed.score(hw, shard=True), flat)
-    assert np.array_equal(packed.score(hw), flat)   # auto
-
-    # sweep: workload rows pmap across devices, bit-identical grid
-    sweep = pack_sweep(specs, workloads, [MIX] * len(workloads))
-    grid = sweep.score(hw, shard=False)
-    sharded = sweep.score(hw, shard=True)
-    assert np.array_equal(sharded, grid)
-    assert np.array_equal(np.argmin(sharded, axis=1),
-                          np.argmin(grid, axis=1))
+    flat = packed.score(hw)
+    sweep = pack_sweep(specs, workloads, mixes)
+    grid = sweep.score(hw)
     # 1e-6 against the grouped oracle, like every other engine pairing
-    np.testing.assert_allclose(sharded, sweep.score(hw, engine="grouped"),
+    np.testing.assert_allclose(grid, sweep.score(hw, engine="grouped"),
                                rtol=1e-6)
-
-    # zero recompiles on repeat sharded scores AND hardware swaps
-    sweep.score(hw3(), shard=True)   # warm both tables
-    before = devicecost.trace_count()
-    for _ in range(3):
-        assert np.array_equal(sweep.score(hw, shard=True), grid)
-        sweep.score(hw3(), shard=True)
-    packed.score(hw, shard=True)
-    assert devicecost.trace_count() == before
-
-    # single-row sweeps fall back to segment-range sharding, same grid
-    one_row = pack_sweep(specs, [BASE], [MIX])
-    assert np.array_equal(one_row.score(hw, shard=True),
-                          one_row.score(hw, shard=False))
 
     # the shard pool partitions and merges bit-identically
     pool = ScoringShardPool(min_cells_per_shard=1)
-    assert pool.n_shards == min(n_devices, len(pool.devices))
+    assert pool.n_shards == n_devices
     totals, used = pool.score_frontier(packed, hw)
     assert used > 1
     assert np.array_equal(totals, flat)
     pooled, used = pool.score_sweep(sweep, hw)
     assert used > 1
     assert np.array_equal(pooled, grid)
+    assert np.array_equal(np.argmin(pooled, axis=1), np.argmin(grid, axis=1))
+
+    # zero recompiles on repeat pool scores AND hardware swaps
+    pool.score_sweep(sweep, hw3())   # warm both tables on every device
+    before = devicecost.trace_count()
+    for _ in range(3):
+        assert np.array_equal(pool.score_sweep(sweep, hw)[0], grid)
+        pool.score_sweep(sweep, hw3())
+    pool.score_frontier(packed, hw)
+    assert devicecost.trace_count() == before
+
+    # a single-row sweep splits on designs like any other, same grid
+    one_row = pack_sweep(specs, [BASE], [MIX])
+    assert np.array_equal(pool.score_sweep(one_row, hw)[0],
+                          one_row.score(hw))
     pool.close()
 
-    # identical argmins through the public search/sweep surfaces
-    devicecost.set_shard_threshold(devicecost._MAX_FUSED_RECORDS)
-    answer_flat = whatif.workload_sweep(specs, workloads, hw,
-                                        [MIX] * len(workloads))
-    beam_flat = design_beam(BASE, hw, MIX, max_rounds=2)
+    # identical answers run directly and through a service with one
+    # scoring shard per device
+    answer_flat = whatif.workload_sweep(specs, workloads, hw, mixes)
+    best_flat = complete_design((), BASE, hw, mix=MIX, max_depth=2)
     batchcost.clear_caches()
-    devicecost.set_shard_threshold(1)
-    answer_sharded = whatif.workload_sweep(specs, workloads, hw,
-                                           [MIX] * len(workloads))
-    beam_sharded = design_beam(BASE, hw, MIX, max_rounds=2)
-    assert np.array_equal(answer_sharded.totals, answer_flat.totals)
-    assert beam_sharded["design"] == beam_flat["design"]
-    assert beam_sharded["cost_s"] == beam_flat["cost_s"]
+    with DesignCalculatorService([hw], scoring_shards=n_devices,
+                                 shard_min_cells=1) as service:
+        answer_pooled = service.workload_sweep(specs, workloads, hw, mixes)
+        best_pooled = service.complete_design((), BASE, hw, mix=MIX,
+                                              max_depth=2)
+        assert service.stats()["shard_dispatches"] >= 2
+    assert np.array_equal(answer_pooled.totals, answer_flat.totals)
+    assert best_pooled.spec == best_flat.spec
+    assert best_pooled.cost_seconds == best_flat.cost_seconds
 
 
 @pytest.mark.devices(2)
-def test_calibration_with_multiple_devices(device_count):
+def test_one_shard_service_stays_on_one_device(device_count):
+    """A service given one scoring shard scores on one device, however
+    many the process sees: no part is dispatched, and no parameter banks
+    are placed on a second device."""
     assert device_count == 2
-    threshold = devicecost._calibrate_shard_threshold()
-    assert threshold >= devicecost._CALIBRATION_BUCKETS[0]
-    # the lazily-memoized default resolves to some positive cut-over
-    assert devicecost.shard_threshold() >= 1
+    hw = hw1()
+    specs, workloads = _specs(), _workloads()
+    mixes = [MIX] * len(workloads)
+    placed = set(devicecost._BANK_REPLICAS.keys())
+    with DesignCalculatorService([hw], scoring_shards=1,
+                                 shard_min_cells=1) as service:
+        answer = service.workload_sweep(specs, workloads, hw, mixes)
+        assert service.stats()["shard_dispatches"] == 0
+    assert np.array_equal(
+        answer.totals, pack_sweep(specs, workloads, mixes).score(hw))
+    new = set(devicecost._BANK_REPLICAS.keys()) - placed
+    assert all(len(k) == 3 and k[1] == "device" for k in new)
+    assert len({k[2] for k in new}) <= 1
 
 
 @pytest.mark.devices(2)

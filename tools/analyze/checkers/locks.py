@@ -66,9 +66,6 @@ GUARDED_GLOBALS: Dict[str, Dict] = {
     "_MODEL_NAMES": {"owner": "repro.core.devicecost",
                      "locks": _MEMO_LOCKS, "writes_only": True,
                      "dotted": {"devicecost._MODEL_NAMES"}},
-    "_SHARD_STATE": {"owner": "repro.core.devicecost",
-                     "locks": _MEMO_LOCKS, "writes_only": True,
-                     "dotted": {"devicecost._SHARD_STATE"}},
     "_TOTALS": {"owner": "repro.core.spans",
                 "locks": {"_LOCK", "spans._LOCK"}, "writes_only": False,
                 "dotted": {"spans._TOTALS"}},
